@@ -47,7 +47,7 @@ DIRTY_FRAC = 0.08            # <=10% of blocks dirty per iteration
 ITERS = 4
 
 CODEC_PAGES = 64             # compressed-vs-raw lane span payload (256 KiB)
-PACK_PAGES = 128             # fused-pack lane window (interpret-friendly)
+PACK_PAGES = 128             # fused-pack lane window
 
 HIGH_WATERMARK = 1 << 20     # backpressure: 1 MiB in flight max
 LOW_WATERMARK = 256 << 10
@@ -232,15 +232,24 @@ def _fused_pack_suite(bench: Bench, comm: Communicator, d: str,
                       label: str) -> None:
     """Fused diff+pack: one device->host payload transfer per shard set.
 
-    The per-span fallback fetches every dirty run separately; the packed
+    The reference path fetches every dirty run separately; the packed
     path must fetch exactly ONE compacted payload (plus one tiny bitmap)
     per ``sync_shards_from_device`` call, asserted from the window's
-    transfer accounting.
+    transfer accounting.  The platform picks the implementation: the
+    lane runs where that is the compiled kernel (a TPU).
     """
     try:
+        import jax
         import jax.numpy as jnp
-    except Exception:
+
+        from repro.kernels.ops import use_pallas
+    except ImportError:
         bench.add(f"fused_pack{label}", 0.0, derived="skipped (no jax)")
+        return
+    if not use_pallas():
+        bench.add(f"fused_pack{label}", 0.0,
+                  derived=f"skipped ({jax.default_backend()} runs the "
+                          "reference path)")
         return
     win = Window.allocate(comm, PACK_PAGES * PAGE, info={
         "alloc_type": "storage",
@@ -255,7 +264,7 @@ def _fused_pack_suite(bench: Bench, comm: Communicator, d: str,
     cur = snap.copy()
     cur[0] += 1.0
     win.sync_shards_from_device(0, [(jnp.asarray(cur), jnp.asarray(snap), 0)],
-                                impl="interpret", blocking=True)
+                                blocking=True)
     snap = cur
     with timer() as tp:
         for _ in range(ITERS):
@@ -265,10 +274,11 @@ def _fused_pack_suite(bench: Bench, comm: Communicator, d: str,
             cur[pages * epp] += 1.0
             win.sync_shards_from_device(
                 0, [(jnp.asarray(cur), jnp.asarray(snap), 0)],
-                impl="interpret", blocking=True)
+                blocking=True)
             snap = cur
     st = win.device_sync_stats()
     win.free()
+    assert st["pallas_syncs"] == st["syncs"], st
     per_sync = st["payload_transfers"] / max(1, st["syncs"])
     bench.add(f"fused_pack{label}", tp["s"], calls=ITERS,
               derived=f"{st['payload_bytes'] >> 10}KiB in "

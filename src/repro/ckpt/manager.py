@@ -64,7 +64,7 @@ class RestoreResult:
 
 
 def _crc(arr: np.ndarray) -> int:
-    return zlib.crc32(np.ascontiguousarray(arr).view(np.uint8).ravel().tobytes())
+    return zlib.crc32(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
 
 
 class CheckpointManager:
@@ -76,7 +76,7 @@ class CheckpointManager:
                  mechanism: str = "cached", writeback_interval: float | None = None,
                  striping_factor: int = 1, striping_unit: int = 1 << 20,
                  page_size_hint: int | None = None, snapshot_diff: bool = True,
-                 replication: int = 1):
+                 replication: int = 1, cache_bytes: int | None = None):
         """``replication=k`` passes the ``storage_alloc_replication`` hint
         to both checkpoint windows: every save's flush then mirrors the
         changed pages to k-1 replica ranks *before* the manifest commits
@@ -84,6 +84,11 @@ class CheckpointManager:
         ``restore`` whose primary rank died reads transparently from a
         replica -- the checkpoint survives rank death without a restart.
         Requires ``comm.size >= k`` (clamped otherwise, like every hint).
+
+        ``cache_bytes`` bounds each window's page cache (default: the
+        whole window).  With a bound and ``snapshot_diff=False`` a save
+        streams the tree through it to storage in full: no compare on
+        write, which would read the old pages back from storage first.
         """
         self.directory = directory
         self.comm = comm
@@ -111,8 +116,8 @@ class CheckpointManager:
                 info["storage_alloc_replication"] = str(replication)
             self.windows[name] = WindowedPyTree.allocate(
                 comm, self.specs, info, rank=self.rank, mechanism=mechanism,
-                writeback_interval=writeback_interval)
-            if not snapshot_diff:
+                writeback_interval=writeback_interval, cache_bytes=cache_bytes)
+            if not snapshot_diff and cache_bytes is None:
                 # selective sync even under whole-tree puts:
                 for seg in self._segments(self.windows[name]):
                     if hasattr(seg, "backing") and hasattr(seg.backing,

@@ -190,11 +190,13 @@ class WindowedPyTree:
                  info=None, *, rank: int = 0, memory_budget: int | None = None,
                  mechanism: str = "cached", shared_file: bool = False,
                  writeback_interval: float | None = None,
+                 cache_bytes: int | None = None,
                  block_bytes: int = 1 << 22) -> "WindowedPyTree":
         slots, total = cls.layout(specs)
         win = Window.allocate(comm, total, info=info, memory_budget=memory_budget,
                               mechanism=mechanism, shared_file=shared_file,
-                              writeback_interval=writeback_interval)
+                              writeback_interval=writeback_interval,
+                              cache_bytes=cache_bytes)
         return cls(win, slots, rank, block_bytes=block_bytes)
 
     @classmethod

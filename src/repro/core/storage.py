@@ -244,6 +244,14 @@ class StripedFile:
                 buf[bpos + len(chunk):bpos + length] = b"\0" * (length - len(chunk))
         return bytes(buf)
 
+    def preadinto(self, offset: int, out: np.ndarray) -> None:
+        """``out.nbytes`` bytes at ``offset`` into the uint8 array ``out``,
+        with no intermediate copy; past EOF reads as zeros."""
+        for fidx, foff, length, bpos in self._segments(offset, out.nbytes):
+            dst = out[bpos:bpos + length]
+            got = os.preadv(self._fds[fidx], [dst], foff)
+            dst[got:] = 0
+
     def pwrite(self, offset: int, data: bytes | memoryview) -> None:
         mv = memoryview(data)
         for fidx, foff, length, bpos in self._segments(offset, len(mv)):
@@ -390,6 +398,13 @@ class MmapBacking(_BackingBase):
                 pass
 
 
+#: bytes one pwrite of a flush carries at most
+FLUSH_CHUNK = 64 << 20
+#: a span at least this large that the page cache could only take by
+#: evicting, and of which no page is cached, streams past the cache
+STREAM_MIN_BYTES = 1 << 20
+
+
 class CachedBacking(_BackingBase):
     """User-level page cache over a (possibly striped) file.
 
@@ -398,7 +413,10 @@ class CachedBacking(_BackingBase):
     (clock) eviction; writes mark blocks dirty; eviction of a dirty block
     writes it back first.  A background flusher thread emulates
     ``vm.dirty_writeback_centisecs``; ``dirty_ratio`` bounds the dirty
-    fraction before writes force a flush (``vm.dirty_ratio``).
+    fraction before writes force a flush (``vm.dirty_ratio``).  A span of
+    at least ``STREAM_MIN_BYTES`` that the cache could take only by
+    evicting, and none of whose pages is resident, streams straight to or
+    from the file (written pages are fsynced by the next sync).
     """
 
     def __init__(self, path: str, size: int, *, offset: int = 0,
@@ -427,6 +445,7 @@ class CachedBacking(_BackingBase):
         self._used = 0
         self.dirty_ratio = dirty_ratio
         self._io_lock = threading.RLock()
+        self._unsynced = 0  # bytes written past the cache since the last fsync
         self.faults = 0
         self.evictions = 0
         self._flusher: "_Flusher | None" = None
@@ -483,10 +502,58 @@ class CachedBacking(_BackingBase):
         return s
 
     def _free_slot(self) -> int:
+        # slots fill in index order and an eviction hands its slot straight
+        # to the next block, so until the cache is full the next unused
+        # slot is free: no scan over the whole cache per first touch
+        if self._used < self.capacity and self._block_of[self._used] < 0:
+            return self._used
         free = np.flatnonzero(self._block_of < 0)
         if len(free) == 0:
             return self._evict_one()
         return int(free[0])
+
+    def _streams(self, b0: int, b1: int) -> bool:
+        """Pages ``[b0, b1)`` bypass the cache: a large span that would
+        only fit by evicting, with none of its pages resident (so the file
+        holds their latest bytes).  A bounded cache then costs one
+        syscall per chunk, not one per page, for data it cannot keep."""
+        n = b1 - b0
+        return (n * self.page_size >= STREAM_MIN_BYTES
+                and self._used + n > self.capacity
+                and not (self._slot_of[b0:b1] >= 0).any())
+
+    def _load_run(self, b0: int, b1: int) -> bool:
+        """Fault pages ``[b0, b1)``, none resident, into the next free
+        slots with one read; False unless those slots are free and in
+        order (always so until a cache first fills)."""
+        n, s0 = b1 - b0, self._used
+        if (n < 2 or s0 + n > self.capacity
+                or (self._slot_of[b0:b1] >= 0).any()
+                or (self._block_of[s0:s0 + n] >= 0).any()):
+            return False
+        lo = b0 * self.page_size
+        hi = min(b1 * self.page_size, self.size)
+        flat = self._slots[s0:s0 + n].reshape(-1)
+        for c in range(0, hi - lo, FLUSH_CHUNK):
+            self.file.preadinto(lo + c, flat[c:min(c + FLUSH_CHUNK, hi - lo)])
+        flat[hi - lo:] = 0
+        self._slot_of[b0:b1] = np.arange(s0, s0 + n)
+        self._block_of[s0:s0 + n] = np.arange(b0, b1)
+        self._refbit[s0:s0 + n] = True
+        self._used += n
+        self.faults += n
+        return True
+
+    def _write_through(self, offset: int, data: np.ndarray) -> None:
+        """Whole pages straight to the file; they stay clean and the next
+        sync fsyncs them."""
+        for c in range(0, data.nbytes, FLUSH_CHUNK):
+            self.file.pwrite(offset + c, data[c:c + FLUSH_CHUNK])
+        b0, b1 = self.tracker.block_range(offset, data.nbytes)
+        with self.tracker._lock:
+            self.tracker._bits[b0:b1] = False
+        self._unsynced += data.nbytes
+        self.bytes_flushed += data.nbytes
 
     # -- public interface ---------------------------------------------------
     def read(self, offset: int, nbytes: int) -> np.ndarray:
@@ -494,6 +561,15 @@ class CachedBacking(_BackingBase):
         out = np.empty(nbytes, dtype=np.uint8)
         with self._io_lock:
             b0, b1 = self.tracker.block_range(offset, nbytes)
+            if self._streams(b0, b1):
+                for c in range(0, nbytes, FLUSH_CHUNK):
+                    self.file.preadinto(offset + c, out[c:c + FLUSH_CHUNK])
+                return out
+            if self._load_run(b0, b1):
+                lo = offset - b0 * self.page_size
+                s0 = int(self._slot_of[b0])
+                out[:] = self._slots[s0:s0 + b1 - b0].reshape(-1)[lo:lo + nbytes]
+                return out
             # fast path: aligned read, everything resident -> one gather
             if (offset % self.page_size == 0 and nbytes % self.page_size == 0
                     and nbytes and (self._slot_of[b0:b1] >= 0).all()):
@@ -561,17 +637,23 @@ class CachedBacking(_BackingBase):
         ps = self.page_size
         with self._io_lock:
             # split into [head | page-aligned bulk | tail]: the bulk span is
-            # one vectorized scatter instead of a python loop per page
+            # one vectorized scatter (or one streamed write) instead of a
+            # python loop per page
             a = -(-offset // ps) * ps
             b = (offset + nbytes) // ps * ps
             done = False
             if not self.compare_on_write and b - a >= ps:
-                if self._write_bulk(a, data[a - offset: b - offset]):
+                bulk = data[a - offset: b - offset]
+                if self._streams(a // ps, b // ps):
+                    self._write_through(a, bulk)
+                    done = True
+                else:
+                    done = self._write_bulk(a, bulk)
+                if done:
                     if a > offset:
                         self._write_slow(offset, data[: a - offset])
                     if offset + nbytes > b:
                         self._write_slow(b, data[b - offset:])
-                    done = True
             if not done:
                 self._write_slow(offset, data)
             # vm.dirty_ratio: too many dirty pages => synchronous flush.
@@ -594,7 +676,7 @@ class CachedBacking(_BackingBase):
             raise RuntimeError("backing is closed")
         with self._io_lock:
             self.sync_count += 1
-            n = self._flush_locked(full=full, mask=mask)
+            n = self._flush_locked(full=full, mask=mask) + self._unsynced
             if n:
                 try:
                     self.file.fsync()
@@ -604,6 +686,7 @@ class CachedBacking(_BackingBase):
                     # retry replays everything (never skips).
                     self.tracker.mark(0, self.size)
                     raise
+                self._unsynced = 0
             return n
 
     def _flush_locked(self, full: bool = False,
@@ -614,13 +697,17 @@ class CachedBacking(_BackingBase):
         flushed = 0
         try:
             for b0, b1 in dirty_runs(take):
-                # coalesce the run: gather resident slots, one pwrite per span
+                # coalesce the run: gather resident slots, one pwrite per
+                # chunk of it -- a bounded copy, and never past Linux's cap
+                # of 2 GiB on what one write moves
                 slots = self._slot_of[b0:b1]
                 resident = slots >= 0
                 if resident.all() and b1 * self.page_size <= self.size:
-                    buf = self._slots[slots].reshape(-1)
-                    self.file.pwrite(b0 * self.page_size, buf.tobytes())
-                    flushed += buf.nbytes
+                    step = max(1, FLUSH_CHUNK // self.page_size)
+                    for c0 in range(0, b1 - b0, step):
+                        buf = self._slots[slots[c0:c0 + step]].reshape(-1)
+                        self.file.pwrite((b0 + c0) * self.page_size, buf)
+                        flushed += buf.nbytes
                     continue
                 for blk in range(b0, b1):
                     s = int(self._slot_of[blk])

@@ -838,19 +838,24 @@ class Window:
     def device_sync_stats(self) -> dict:
         """Device->host transfer accounting for selective device sync.
 
-        ``syncs`` counts :meth:`sync_shards_from_device` calls;
-        ``payload_transfers`` counts device->host *data* fetches (the fused
-        diff+pack path does exactly ONE per shard set, however fragmented
-        the dirty set); ``bitmap_transfers`` the tiny per-set bitmap
-        fetches; ``span_transfers`` per-span slice fetches on the host
-        fallback path; ``payload_bytes``/``logical_bytes`` the packed bytes
-        fetched vs the changed bytes shipped.
+        ``syncs`` counts :meth:`sync_shards_from_device` calls, and
+        ``pallas_syncs`` / ``interpret_syncs`` / ``ref_syncs`` split them by
+        the kernel implementation ``impl`` resolved to (compiled Pallas,
+        Pallas interpreter, jnp reference); ``payload_transfers`` counts
+        device->host *data* fetches (the diff+pack path does exactly ONE
+        per shard set, however fragmented the dirty set);
+        ``bitmap_transfers`` the tiny per-set bitmap fetches;
+        ``span_transfers`` per-span slice fetches on the reference path;
+        ``payload_bytes``/``logical_bytes`` the packed bytes fetched vs the
+        changed bytes shipped.
         """
         st = getattr(self, "_dev_sync_stats", None)
         if st is None:
             st = self._dev_sync_stats = {
-                "syncs": 0, "payload_transfers": 0, "bitmap_transfers": 0,
-                "span_transfers": 0, "payload_bytes": 0, "logical_bytes": 0}
+                "syncs": 0, "pallas_syncs": 0, "interpret_syncs": 0,
+                "ref_syncs": 0, "payload_transfers": 0,
+                "bitmap_transfers": 0, "span_transfers": 0,
+                "payload_bytes": 0, "logical_bytes": 0}
         return st
 
     #: pending-list length that triggers a prune pass in _register --
@@ -1669,14 +1674,13 @@ class Window:
             raise WindowError("cur/snap dtype mismatch")
 
     def _device_flags(self, rank: int, cur, snap, *,
-                      impl: str | None, tile_elems: int | None) -> np.ndarray:
+                      impl: str | None) -> np.ndarray:
         """Per-page-span changed flags from the Pallas dirty_diff kernel."""
         from repro.kernels.ops import dirty_blocks  # lazy: jax-free core
         self._check_shard_pair(cur, snap)
         _, block_elems, _ = self._device_page_geometry(rank, cur.dtype)
         return np.asarray(dirty_blocks(cur, snap, block_elems=block_elems,
-                                       tile_elems=tile_elems, impl=impl),
-                          dtype=bool)
+                                       impl=impl), dtype=bool)
 
     def _flags_to_window_mask(self, rank: int, flags: np.ndarray, dtype,
                               nelems: int, target_disp: int) -> np.ndarray:
@@ -1695,8 +1699,7 @@ class Window:
         return mask
 
     def device_dirty_mask(self, rank: int, cur, snap, *, target_disp: int = 0,
-                          impl: str | None = None,
-                          tile_elems: int | None = None) -> np.ndarray:
+                          impl: str | None = None) -> np.ndarray:
         """Window-block mask of pages where ``cur`` differs from ``snap``.
 
         Runs the Pallas ``dirty_diff`` kernel (one flag per ``page_size``
@@ -1705,15 +1708,13 @@ class Window:
         rank's segment.  The mask feeds ``flush_async(mask=...)`` or
         ``DirtyTracker.mark_blocks``.
         """
-        flags = self._device_flags(rank, cur, snap, impl=impl,
-                                   tile_elems=tile_elems)
+        flags = self._device_flags(rank, cur, snap, impl=impl)
         nelems = int(np.prod(np.shape(cur), dtype=np.int64))
         return self._flags_to_window_mask(rank, flags, cur.dtype, nelems,
                                           target_disp)
 
     def sync_from_device(self, rank: int, cur, snap, *, target_disp: int = 0,
-                         blocking: bool = False, impl: str | None = None,
-                         tile_elems: int | None = None):
+                         blocking: bool = False, impl: str | None = None):
         """Selective device-state sync: diff on-device, ship + flush only
         changed pages.
 
@@ -1741,13 +1742,11 @@ class Window:
         to the rank; mixing in a blocking ``put`` needs ``flush(rank)``).
         """
         return self.sync_shards_from_device(
-            rank, [(cur, snap, target_disp)], blocking=blocking, impl=impl,
-            tile_elems=tile_elems)
+            rank, [(cur, snap, target_disp)], blocking=blocking, impl=impl)
 
     def sync_shards_from_device(self, rank: int, shards, *,
                                 blocking: bool = False,
-                                impl: str | None = None,
-                                tile_elems: int | None = None):
+                                impl: str | None = None):
         """Sharded :meth:`sync_from_device`: one merged mask, one flush.
 
         ``shards`` is an iterable of ``(cur, snap, target_disp)`` regions
@@ -1758,14 +1757,15 @@ class Window:
         that mask in one masked span-write -- still one round trip per
         target rank, however many shards contributed.
 
-        Device->host movement depends on which kernel runs.  When the
-        fused ``diff_pack`` kernel is available (``impl`` resolves to
-        ``pallas`` or ``interpret``), each shard's changed blocks are
-        compacted *on device* (prefix-sum placement) and every shard's
-        compacted buffer crosses PCIe in ONE contiguous transfer per shard
-        set -- plus one tiny bitmap fetch -- regardless of how fragmented
-        the dirty set is.  The host fallback (``impl='ref'``, or a non-TPU
-        default) fetches one slice per changed span.  Both paths derive
+        Device->host movement depends on which kernel runs.  ``impl``
+        defaults to the compiled Pallas kernels on a TPU and to the jnp
+        reference elsewhere; ``device_sync_stats`` counts which one ran.
+        On the kernel path (``pallas`` or ``interpret``) each shard's
+        changed blocks are compacted *on device* (prefix-sum placement)
+        and every shard's compacted buffer crosses PCIe in ONE contiguous
+        transfer per shard set -- plus one tiny bitmap fetch -- regardless
+        of how fragmented the dirty set is.  The reference path
+        (``impl='ref'``) fetches one slice per changed span.  Both paths derive
         their spans from the same ``changed_elem_spans`` geometry, so the
         bytes shipped are identical; see :meth:`device_sync_stats` for the
         transfer accounting.  Downstream, the spans may additionally ride
@@ -1782,24 +1782,24 @@ class Window:
         or the bytes directly with ``blocking=True``.
         """
         from repro.kernels.dirty_diff import changed_elem_spans
-        from repro.kernels.ops import use_pallas
+        from repro.kernels.ops import resolve_impl
         shards = list(shards)
         if not shards:
             raise WindowError(
                 "sync_shards_from_device requires at least one shard")
         self._check_shard_overlap(shards)
-        resolved = impl or ("pallas" if use_pallas() else "ref")
+        resolved = resolve_impl(impl)
         stats = self.device_sync_stats()
         stats["syncs"] += 1
+        stats[f"{resolved}_syncs"] += 1
         if resolved in ("pallas", "interpret"):
             spans, mask = self._packed_device_spans(rank, shards, resolved,
-                                                    tile_elems, stats)
+                                                    stats)
         else:
             spans = []
             mask = None
             for cur, snap, target_disp in shards:
-                flags = self._device_flags(rank, cur, snap, impl=resolved,
-                                           tile_elems=tile_elems)
+                flags = self._device_flags(rank, cur, snap, impl=resolved)
                 _, block_elems, _ = self._device_page_geometry(rank,
                                                                cur.dtype)
                 itemsize = np.dtype(cur.dtype).itemsize
@@ -1808,7 +1808,7 @@ class Window:
                 m = self._flags_to_window_mask(rank, flags, cur.dtype,
                                                nelems, target_disp)
                 mask = m if mask is None else mask | m
-                # host fallback: one device->host slice per changed span
+                # reference path: one device->host slice per changed span
                 # (same changed_elem_spans geometry as the packed path)
                 cur_flat = cur.reshape(-1)
                 for lo_e, hi_e in changed_elem_spans(flags, block_elems,
@@ -1846,7 +1846,7 @@ class Window:
                     "in list order")
 
     def _packed_device_spans(self, rank: int, shards, impl: str,
-                             tile_elems: int | None, stats: dict):
+                             stats: dict):
         """Fused-kernel span gathering: ONE payload transfer per shard set.
 
         Runs ``dirty_pack`` per shard (bitmap + on-device compacted dirty
@@ -1855,10 +1855,8 @@ class Window:
         one more, then rebuilds the span list host-side from the shared
         ``changed_elem_spans`` geometry (``packed_run_layout``).
         """
-        import jax
         import jax.numpy as jnp
 
-        from repro.kernels.dirty_diff import _bit_view
         from repro.kernels.ops import dirty_pack
         from repro.kernels.pack_diff import packed_run_layout
         per = []
@@ -1866,8 +1864,7 @@ class Window:
             self._check_shard_pair(cur, snap)
             _, block_elems, _ = self._device_page_geometry(rank, cur.dtype)
             flags_d, packed_d, _count_d = dirty_pack(
-                cur, snap, block_elems=block_elems, tile_elems=tile_elems,
-                impl=impl)
+                cur, snap, block_elems=block_elems, impl=impl)
             per.append((flags_d, packed_d, cur, target_disp, block_elems))
         # one bitmap fetch covers every shard (int32 flags, concatenated)
         flags_host = np.asarray(jnp.concatenate([p[0] for p in per])
@@ -1881,18 +1878,14 @@ class Window:
             split += flags_d.shape[0]
             shard_flags.append(f)
             k = int(f.sum())
-            if k:
-                rows = packed_d[:k]
-                u8 = (rows if rows.dtype == jnp.uint8
-                      else jax.lax.bitcast_convert_type(
-                          _bit_view(rows), jnp.uint8))
-                parts.append(u8.reshape(-1))
+            if k:  # uint32 rows of one page each, whatever the dtype
+                parts.append(packed_d[:k])
         spans: list[tuple[int, np.ndarray]] = []
         mask: np.ndarray | None = None
         if parts:
             payload = np.asarray(parts[0] if len(parts) == 1
                                  else jnp.concatenate(parts))
-            payload = payload.view(np.uint8)
+            payload = payload.reshape(-1).view(np.uint8)
             stats["payload_transfers"] += 1
             stats["payload_bytes"] += payload.nbytes
         else:

@@ -9,30 +9,39 @@ tiny bitmap plus the dirty blocks then cross PCIe, feeding the same
 ``DirtyTracker`` bitmap as the host-side compare-on-write path
 (``Window.sync_from_device`` / ``flush_async(mask=...)``).
 
-Layout: tensors flattened to (nblocks, block_elems); grid (nblocks, ntiles)
-with the tile dimension innermost, so one storage block is scanned
-``tile_elems`` at a time (blocks far larger than VMEM stream through the
-same resident (1,) output flag, OR-accumulating per tile); out: (nblocks,)
-int32 (1 = changed).
+Layout: every block is a whole number of (8, 128) uint32 tiles -- the
+inputs are ``(nblocks, S, 128)`` words with ``S % 8 == 0`` (a 4 KiB page
+is exactly one tile; ``repro.kernels.ops`` builds this view from any
+dtype and zero-pads smaller blocks).  Each grid step compares ``block_rows`` blocks
+at once, folds each block's tiles to one 128-lane row, and transposes so
+the step's flags leave as one lane-dense ``(1, block_rows)`` int32 row.
+The last step may be partial: the rows it reads past ``nblocks`` are
+garbage and their flags are cropped.
 
-Dtype generality: inexact dtypes are bitcast to same-width unsigned ints
-before the compare, so the kernel tests *bit-pattern* equality -- an
-unchanged block full of NaNs stays clean (IEEE ``NaN != NaN`` would dirty
-it), matching the host page cache's byte-level compare exactly.
+The compare is on uint32 *bit patterns*, so an unchanged block full of
+NaNs stays clean (IEEE ``NaN != NaN`` would dirty it), matching the host
+page cache's byte-level compare exactly.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["dirty_diff_tpu", "changed_elem_spans", "DEFAULT_TILE_ELEMS"]
+__all__ = ["dirty_diff_tpu", "changed_elem_spans"]
 
-# Default tile: multiple of every dtype's minimum lane tiling (8*128 f32,
-# 16*128 bf16, 32*128 int8) and small enough that two resident input tiles
-# stay well under VMEM at any supported itemsize.
-DEFAULT_TILE_ELEMS = 4096
+LANES = 128
+#: words each input block of one grid step aims at (1 MiB of uint32):
+#: two inputs, double-buffered, stay well inside the default scoped VMEM
+STEP_WORDS = 1 << 18
+
+
+def _default_block_rows(sublanes: int) -> int:
+    """Blocks per grid step for blocks of ``sublanes`` x 128 words."""
+    return max(8, STEP_WORDS // (sublanes * LANES)) // 8 * 8
 
 
 def changed_elem_spans(flags, block_elems: int,
@@ -56,52 +65,40 @@ def changed_elem_spans(flags, block_elems: int,
     return out
 
 
-def _kernel(cur_ref, snap_ref, flag_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():  # first tile of each block resets the revisited flag
-        flag_ref[0] = 0
-
-    flag_ref[0] |= jnp.any(cur_ref[0] != snap_ref[0]).astype(jnp.int32)
-
-
-def _bit_view(x: jax.Array) -> jax.Array:
-    """Same-width unsigned-int view for exact bit-pattern comparison."""
-    if jnp.issubdtype(x.dtype, jnp.inexact):
-        return jax.lax.bitcast_convert_type(
-            x, jnp.dtype(f"uint{x.dtype.itemsize * 8}"))
-    return x
+def _kernel(sublanes, cur_ref, snap_ref, flag_ref):
+    ne = (cur_ref[...] != snap_ref[...]).astype(jnp.int32)
+    rows = ne.shape[0] // sublanes
+    per_block = jnp.max(ne.reshape(rows, sublanes, LANES), axis=1)
+    flag_ref[0] = jnp.max(per_block.T, axis=0, keepdims=True)
 
 
 def dirty_diff_tpu(cur: jax.Array, snap: jax.Array, *,
-                   tile_elems: int | None = None,
+                   block_rows: int | None = None,
                    interpret: bool = False) -> jax.Array:
-    """cur, snap: (nblocks, block_elems) same dtype -> (nblocks,) int32.
-
-    ``tile_elems`` bounds per-step VMEM residency; ``block_elems`` that are
-    not a tile multiple are zero-padded on both inputs (equal padding never
-    marks a block dirty).
-    """
-    assert cur.shape == snap.shape and cur.dtype == snap.dtype
-    cur, snap = _bit_view(cur), _bit_view(snap)
-    nb, be = cur.shape
-    if tile_elems is None:
-        tile_elems = DEFAULT_TILE_ELEMS
-    tile_elems = max(1, min(int(tile_elems), be))
-    pad = (-be) % tile_elems
-    if pad:
-        cur = jnp.pad(cur, ((0, 0), (0, pad)))
-        snap = jnp.pad(snap, ((0, 0), (0, pad)))
-    ntiles = (be + pad) // tile_elems
-    return pl.pallas_call(
-        _kernel,
-        grid=(nb, ntiles),
-        in_specs=[
-            pl.BlockSpec((1, tile_elems), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_elems), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nb,), jnp.int32),
+    """cur, snap: (nblocks, S, 128) uint32, ``S % 8 == 0`` -> (nblocks,)
+    int32 (1 = changed)."""
+    if cur.shape != snap.shape or cur.dtype != snap.dtype:
+        raise ValueError("cur/snap shape or dtype mismatch")
+    nb, sub, lanes = cur.shape
+    if lanes != LANES or sub % 8 or cur.dtype != jnp.uint32:
+        raise ValueError(f"need (nblocks, 8k, 128) uint32 words, got "
+                         f"{cur.shape} {cur.dtype}")
+    rows = block_rows or _default_block_rows(sub)
+    if rows % 8:
+        raise ValueError(f"block_rows must be a multiple of 8, got {rows}")
+    if nb < rows:  # a single step: pad it whole (small by construction)
+        rows = -(-nb // 8) * 8
+        cur = jnp.pad(cur, ((0, rows - nb), (0, 0), (0, 0)))
+        snap = jnp.pad(snap, ((0, rows - nb), (0, 0), (0, 0)))
+    steps = pl.cdiv(cur.shape[0], rows)
+    flat = (cur.shape[0] * sub, LANES)  # same tiled layout: a bitcast
+    out = pl.pallas_call(
+        functools.partial(_kernel, sub),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((rows * sub, LANES), lambda i: (i, 0))] * 2,
+        out_specs=pl.BlockSpec((1, 1, rows), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps, 1, rows), jnp.int32),
         interpret=interpret,
-    )(cur, snap)
+        name="dirty_diff",
+    )(cur.reshape(flat), snap.reshape(flat))
+    return out.reshape(-1)[:nb]

@@ -1,6 +1,6 @@
 """Public jit-ready kernel wrappers with backend dispatch + padding.
 
-On TPU the Pallas kernels run; elsewhere (this CPU container, unit tests)
+On TPU the Pallas kernels run; elsewhere (CPU runs, unit tests)
 the pure-jnp references execute, with ``interpret=True`` available to run
 the actual kernel bodies on CPU for validation.  Wrappers normalize layouts
 and pad to block multiples so callers never see alignment constraints.
@@ -14,19 +14,17 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.dirty_diff import _bit_view, dirty_diff_tpu
+from repro.kernels.dirty_diff import dirty_diff_tpu
 from repro.kernels.flash_attention import flash_attention_tpu
-from repro.kernels.pack_diff import diff_pack_ref, diff_pack_tpu
+from repro.kernels.pack_diff import diff_pack_tpu
 from repro.kernels.rg_lru import rg_lru_tpu
 from repro.kernels.ssd_scan import ssd_scan_tpu
 
 __all__ = ["flash_attention", "ssd_scan", "rg_lru_scan", "dirty_blocks",
-           "dirty_pack", "use_pallas", "PACK_VMEM_LIMIT"]
+           "dirty_pack", "use_pallas", "resolve_impl"]
 
-# The fused pack kernel keeps its compacted output resident in VMEM for the
-# whole pass; compiled (non-interpret) dispatch falls back to the host
-# reference above this many packed-buffer bytes.
-PACK_VMEM_LIMIT = 8 << 20
+#: uint32 words in one (8, 128) TPU tile: the device-sync kernels' unit
+TILE_WORDS = 8 * 128
 
 
 def use_pallas() -> bool:
@@ -88,57 +86,96 @@ def rg_lru_scan(a, gx, *, block=256, impl: str | None = None):
     return y[:, :S]
 
 
-def dirty_blocks(cur, snap, *, block_elems=1024, tile_elems=None,
+def _block_words(x, block_elems: int):
+    """Flatten ``x``, zero-pad to a block multiple, and view it as
+    ``(nblocks, words)`` uint32 -- the bit patterns, whatever the dtype."""
+    x = jnp.asarray(x).reshape(-1)
+    nbytes = block_elems * x.dtype.itemsize
+    if nbytes % 4:
+        raise ValueError(f"a block of {block_elems} x {x.dtype} is "
+                         f"{nbytes} bytes, not a whole number of words")
+    pad = (-x.shape[0]) % block_elems
+    if pad:
+        x = jnp.pad(x, (0, pad))
+    size = x.dtype.itemsize
+    if size >= 4:
+        x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    else:
+        # little-endian: element j of a word fills bits [8*size*j, ...).
+        # Strided slices and shifts, not a (.., k)-shaped bitcast, whose
+        # minor dim of k would be padded to 128 lanes on a TPU.
+        u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * size}"))
+        k = 4 // size
+        x = u[0::k].astype(jnp.uint32)
+        for j in range(1, k):
+            x = x | (u[j::k].astype(jnp.uint32) << (8 * size * j))
+    return x.reshape(-1, nbytes // 4)
+
+
+def _tiles(x):
+    """(nblocks, words) -> (nblocks, S, 128): each block zero-padded to
+    whole (8, 128) tiles (a 4 KiB page is exactly one)."""
+    nb, words = x.shape
+    pad = (-words) % TILE_WORDS
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)))
+    return x.reshape(nb, -1, 128)
+
+
+def resolve_impl(impl: str | None) -> str:
+    """The kernel implementation ``impl`` names; None picks the platform's
+    (compiled Pallas on a TPU, the jnp reference elsewhere)."""
+    impl = impl or ("pallas" if use_pallas() else "ref")
+    if impl not in ("pallas", "interpret", "ref"):
+        raise ValueError(f"unknown impl {impl!r}")
+    return impl
+
+
+_STATIC = ("block_elems", "block_rows", "impl")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def dirty_blocks(cur, snap, *, block_elems=1024, block_rows=None,
                  impl: str | None = None):
     """Flatten two same-shape tensors into blocks; return int32 changed flags.
 
     Feeds DirtyTracker.mark_blocks for device-state incremental checkpoints
     (``Window.sync_from_device`` sizes ``block_elems`` so one flag covers one
-    tracker page).  ``tile_elems`` bounds the kernel's per-step VMEM
-    residency for blocks larger than a VMEM tile.
+    tracker page).  The compare is on bit patterns under every impl, so an
+    unchanged NaN block stays clean.  ``block_rows`` sets how many blocks
+    one kernel grid step compares (a multiple of 8).
     """
-    impl = impl or ("pallas" if use_pallas() else "ref")
-    # bit-pattern view before dispatch so ref and pallas agree: an unchanged
-    # NaN block stays clean under either impl (value compare would dirty it)
-    c = _bit_view(jnp.asarray(cur)).reshape(-1)
-    s = _bit_view(jnp.asarray(snap)).reshape(-1)
-    pad = (-c.shape[0]) % block_elems
-    if pad:
-        c = jnp.pad(c, (0, pad))
-        s = jnp.pad(s, (0, pad))
-    c = c.reshape(-1, block_elems)
-    s = s.reshape(-1, block_elems)
+    impl = resolve_impl(impl)
+    c = _block_words(cur, block_elems)
+    s = _block_words(snap, block_elems)
     if impl == "ref":
         return ref.dirty_diff_ref(c, s)
-    return dirty_diff_tpu(c, s, tile_elems=tile_elems,
+    return dirty_diff_tpu(_tiles(c), _tiles(s), block_rows=block_rows,
                           interpret=(impl == "interpret"))
 
 
-def dirty_pack(cur, snap, *, block_elems=1024, tile_elems=None,
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def dirty_pack(cur, snap, *, block_elems=1024, block_rows=None,
                impl: str | None = None):
-    """Fused diff+pack: ``(flags (nb,) int32, packed (nb, block_elems),
+    """Fused diff+pack: ``(flags (nb,) int32, packed (nb, words) uint32,
     count (1,) int32)``.
 
-    ``packed[:count]`` holds the changed blocks in block order (bit-view
-    dtype), so one device->host fetch of those rows moves every changed
-    byte; ``repro.kernels.pack_diff.packed_run_layout`` maps the bitmap to
-    span geometry shared with the non-fused path.  Layout normalization
-    (bit view, flatten, zero-pad to a block multiple) matches
-    :func:`dirty_blocks` exactly, so the two bitmaps always agree.
+    ``packed[:count]`` holds the changed blocks' bytes in block order, one
+    row of ``block_elems * itemsize / 4`` words per block, so one
+    device->host fetch of those rows moves every changed byte;
+    ``repro.kernels.pack_diff.packed_run_layout`` maps the bitmap to span
+    geometry shared with the non-fused path.  Layout normalization
+    (flatten, zero-pad to a block multiple, word view) matches
+    :func:`dirty_blocks` exactly, so the two bitmaps always agree.  Every
+    impl packs at every size; on a TPU the default is the compiled kernel.
     """
-    impl = impl or ("pallas" if use_pallas() else "ref")
-    c = _bit_view(jnp.asarray(cur)).reshape(-1)
-    s = _bit_view(jnp.asarray(snap)).reshape(-1)
-    pad = (-c.shape[0]) % block_elems
-    if pad:
-        c = jnp.pad(c, (0, pad))
-        s = jnp.pad(s, (0, pad))
-    c = c.reshape(-1, block_elems)
-    s = s.reshape(-1, block_elems)
-    if impl == "ref" or (impl == "pallas"
-                         and c.size * c.dtype.itemsize > PACK_VMEM_LIMIT):
-        return diff_pack_ref(c, s)
-    flags, packed, count = diff_pack_tpu(c, s, tile_elems=tile_elems,
-                                         interpret=(impl == "interpret"))
+    impl = resolve_impl(impl)
+    c = _block_words(cur, block_elems)
+    s = _block_words(snap, block_elems)
+    if impl == "ref":
+        return ref.diff_pack_ref(c, s)
+    flags, packed, count = diff_pack_tpu(
+        _tiles(c), _tiles(s), block_rows=block_rows,
+        interpret=(impl == "interpret"))
     # crop tile padding so a run of packed rows is one contiguous byte blob
-    return flags, packed[:, :block_elems], count
+    return flags, packed.reshape(c.shape[0], -1)[:, :c.shape[1]], count

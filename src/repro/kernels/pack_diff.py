@@ -1,41 +1,45 @@
-"""Fused Pallas diff+pack kernel: changed bitmap + compacted dirty blocks.
+"""Diff + pack on device: changed bitmap + compacted dirty blocks.
 
 ``dirty_diff`` alone leaves the expensive half of selective device sync on
 the host: once the bitmap is known, each changed span still crosses PCIe as
-its own device->host slice (`np.asarray` per span).  This kernel fuses the
-two steps into one streaming pass over (current, snapshot): it emits the
-per-block changed flags *and* a compacted buffer whose first ``count`` rows
-are exactly the changed blocks in block order (prefix-sum placement), so
-the changed bytes cross PCIe as ONE contiguous transfer regardless of how
-fragmented the dirty set is.
+its own device->host slice (`np.asarray` per span).  ``diff_pack_tpu``
+keeps both steps on the device: the ``dirty_diff`` kernel emits the
+per-block changed flags, then the ``pack_rows`` kernel copies every flagged
+block, in block order, into the first ``count`` rows of a packed buffer in
+HBM, so the changed bytes cross PCIe as ONE contiguous transfer regardless
+of how fragmented the dirty set is.
 
-Placement trick: the TPU grid is sequential, so the kernel keeps a running
-``count`` of committed dirty blocks and streams every block's tiles
-*optimistically* into packed row ``count``.  Only after the block's last
-tile, when the accumulated flag is known, is the row claimed
-(``count += flag``); a clean block's rows are simply overwritten by the
-next dirty block.  Rows at index >= final count are garbage and must not be
-read.  The packed output is resident in VMEM for the whole pass, which
-bounds the packable tensor size (see ``PACK_VMEM_LIMIT`` in ops.py); the
-dispatcher falls back to the host reference above it.
+The pack kernel never stages the packed buffer in VMEM, so any size packs:
+the flags arrive in SMEM ``PACK_ROWS`` at a time, and each flagged block is
+one HBM->HBM DMA (a 4 KiB page is one contiguous (8, 128) uint32 tile)
+into packed row ``count``; the TPU grid is sequential, so ``count`` is a
+running prefix sum kept in SMEM.  At most ``MAX_INFLIGHT`` copies are
+in flight at once, and each grid step waits for the DMAs it started.  Rows at index >= final count are uninitialized and must not be
+read.
 
-Bit-pattern semantics match ``dirty_diff``: callers pass bit-views
-(`_bit_view`), so unchanged NaN blocks stay clean and the packed rows hold
-the exact bit patterns of the current tensor.
+Bit-pattern semantics match ``dirty_diff``: the inputs are uint32 word
+views (built by ``repro.kernels.ops``), so unchanged NaN blocks stay
+clean and the packed rows hold the exact bytes of the current tensor.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dirty_diff import DEFAULT_TILE_ELEMS, changed_elem_spans
+from repro.kernels.dirty_diff import changed_elem_spans, dirty_diff_tpu
 
-__all__ = ["diff_pack_tpu", "diff_pack_ref", "packed_run_layout"]
+__all__ = ["diff_pack_tpu", "pack_rows_tpu", "packed_run_layout"]
+
+#: flags per pack grid step: a 1-D int32 SMEM block must match XLA's
+#: T(1024) tiling of the flags vector
+PACK_ROWS = 1024
+#: block DMAs the pack kernel keeps in flight: past it, each new copy
+#: first retires the oldest, so a fully dirty step never queues 1024
+MAX_INFLIGHT = 64
 
 
 def packed_run_layout(flags, block_elems: int,
@@ -57,79 +61,80 @@ def packed_run_layout(flags, block_elems: int,
     return out
 
 
-def _kernel(tile_elems, cur_ref, snap_ref, flag_ref, packed_ref, count_ref):
+def _pack_kernel(flag_ref, cur_hbm, packed_hbm, count_ref, sem):
     i = pl.program_id(0)
-    j = pl.program_id(1)
-    nt = pl.num_programs(1)
 
-    @pl.when((i == 0) & (j == 0))
-    def _init_count():
+    @pl.when(i == 0)
+    def _init():
         count_ref[0] = 0
 
-    @pl.when(j == 0)
-    def _init_flag():
-        flag_ref[0] = 0
+    base = i * PACK_ROWS
 
-    flag_ref[0] |= jnp.any(cur_ref[0] != snap_ref[0]).astype(jnp.int32)
-    # Optimistic placement: stream this tile into the next free packed row;
-    # the row is only claimed below once the whole block is known dirty.
-    packed_ref[pl.ds(count_ref[0], 1),
-               pl.ds(j * tile_elems, tile_elems)] = cur_ref[...]
+    def wait_one():  # every copy moves one block: same-size descriptor
+        pltpu.make_async_copy(cur_hbm.at[0], packed_hbm.at[0], sem).wait()
 
-    @pl.when(j == nt - 1)
-    def _commit():
-        count_ref[0] += flag_ref[0]
+    def issue(r, carry):
+        k, inflight = carry
+        f = flag_ref[r]
+        full = jnp.logical_and(f != 0, inflight == MAX_INFLIGHT)
+
+        @pl.when(full)
+        def _retire():
+            wait_one()
+
+        @pl.when(f != 0)
+        def _copy():
+            pltpu.make_async_copy(cur_hbm.at[base + r], packed_hbm.at[k],
+                                  sem).start()
+
+        return k + f, inflight + f - full.astype(jnp.int32)
+
+    count, inflight = jax.lax.fori_loop(0, PACK_ROWS, issue,
+                                        (count_ref[0], jnp.int32(0)))
+    def drain(_, c):
+        wait_one()
+        return c
+
+    jax.lax.fori_loop(0, inflight, drain, 0)
+    count_ref[0] = count
+
+
+def pack_rows_tpu(flags: jax.Array, cur: jax.Array, *,
+                  interpret: bool = False):
+    """flags (nblocks,) int32 0/1; cur (nblocks, S, 128) ->
+    ``(packed like cur, count (1,) int32)``: ``packed[:count]`` are the
+    flagged blocks of ``cur`` in block order."""
+    nb = cur.shape[0]
+    steps = pl.cdiv(nb, PACK_ROWS)
+    flags = jnp.pad(flags.astype(jnp.int32), (0, steps * PACK_ROWS - nb))
+    return pl.pallas_call(
+        _pack_kernel,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((PACK_ROWS,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec((1,), lambda i: (0,),
+                                memory_space=pltpu.SMEM)],
+        out_shape=[jax.ShapeDtypeStruct(cur.shape, cur.dtype),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="pack_rows",
+    )(flags, cur)
 
 
 def diff_pack_tpu(cur: jax.Array, snap: jax.Array, *,
-                  tile_elems: int | None = None, interpret: bool = False):
-    """cur, snap: (nblocks, block_elems) bit-view uints, same shape/dtype.
+                  block_rows: int | None = None, interpret: bool = False):
+    """cur, snap: (nblocks, S, 128) uint32 words.
 
-    Returns ``(flags (nb,) int32, packed (nb, be_padded) cur.dtype,
-    count (1,) int32)``.  ``packed[:count]`` are the dirty blocks in block
-    order; rows past ``count`` are garbage.  ``be_padded`` rounds
-    ``block_elems`` up to the tile multiple (zero padding, like
-    ``dirty_diff_tpu``, so equal padding never marks a block dirty).
+    Returns ``(flags (nb,) int32, packed (nb, S, 128) uint32, count (1,)
+    int32)``.  ``packed[:count]`` are the dirty blocks in block order;
+    rows past ``count`` are uninitialized.
     """
-    assert cur.shape == snap.shape and cur.dtype == snap.dtype
-    nb, be = cur.shape
-    if tile_elems is None:
-        tile_elems = DEFAULT_TILE_ELEMS
-    tile_elems = max(1, min(int(tile_elems), be))
-    pad = (-be) % tile_elems
-    if pad:
-        cur = jnp.pad(cur, ((0, 0), (0, pad)))
-        snap = jnp.pad(snap, ((0, 0), (0, pad)))
-    bep = be + pad
-    ntiles = bep // tile_elems
-    return pl.pallas_call(
-        functools.partial(_kernel, tile_elems),
-        grid=(nb, ntiles),
-        in_specs=[
-            pl.BlockSpec((1, tile_elems), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile_elems), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((nb, bep), lambda i, j: (0, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
-            jax.ShapeDtypeStruct((nb, bep), cur.dtype),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(cur, snap)
-
-
-def diff_pack_ref(cur: jax.Array, snap: jax.Array):
-    """Eager host reference with identical outputs (padding-free)."""
-    from repro.kernels import ref
-    flags = ref.dirty_diff_ref(cur, snap)
-    f = np.asarray(flags).astype(bool)
-    k = int(f.sum())
-    packed = jnp.zeros_like(cur)
-    if k:
-        packed = packed.at[:k].set(jnp.asarray(np.asarray(cur)[f]))
-    return flags, packed, jnp.asarray([k], jnp.int32)
+    flags = dirty_diff_tpu(cur, snap, block_rows=block_rows,
+                           interpret=interpret)
+    packed, count = pack_rows_tpu(flags, cur, interpret=interpret)
+    return flags, packed, count

@@ -5,7 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_ref", "ssd_scan_ref", "rg_lru_ref", "dirty_diff_ref"]
+__all__ = ["flash_attention_ref", "ssd_scan_ref", "rg_lru_ref", "dirty_diff_ref",
+           "diff_pack_ref"]
 
 _NEG = -1e30
 
@@ -78,3 +79,16 @@ def rg_lru_ref(a, gx):
 def dirty_diff_ref(cur, snap):
     """(nblocks, block_elems) pair -> (nblocks,) int32 changed flags."""
     return jnp.any(cur != snap, axis=-1).astype(jnp.int32)
+
+
+def diff_pack_ref(cur, snap):
+    """(nblocks, ...) pair -> (flags, packed, count), packed[:count] = the
+    changed blocks of ``cur`` in block order, rows past count zero."""
+    flags = dirty_diff_ref(cur.reshape(cur.shape[0], -1),
+                           snap.reshape(snap.shape[0], -1))
+    order = jnp.argsort(1 - flags, stable=True)  # dirty blocks first
+    count = jnp.sum(flags, keepdims=True)
+    keep = jnp.arange(cur.shape[0]) < count
+    packed = jnp.where(keep.reshape((-1,) + (1,) * (cur.ndim - 1)),
+                       cur[order], 0).astype(cur.dtype)
+    return flags, packed, count.astype(jnp.int32)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.configs import ARCHS, get_config
 from repro.core import Communicator
 from repro.models import init_cache_specs, init_params, param_specs
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import Engine, SessionStore
 
 
@@ -30,6 +31,7 @@ def main() -> None:
                     help="path for a window-backed resumable session")
     ap.add_argument("--session-factor", default="0.5")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_params(param_specs(cfg), jax.random.PRNGKey(0))

@@ -114,19 +114,15 @@ def _moe_mlp_shard_map(cfg, p, x, mesh, *, capacity=None):
         y = jnp.zeros((T_loc, D), xf.dtype).at[tok].add(contrib)
         return jax.lax.psum(y, "model"), aux
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax import shard_map
     xf = x.reshape(T, D)
     weights = ([p["we_up"], p["we_gate"], p["we_down"]] if gated
                else [p["we_up"], p["we_down"]])
     espec = P("model", None, None)
     in_specs = (P(dp, None), P(None, None)) + (espec,) * len(weights)
     out_specs = (P(dp, None), P())
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False)(xf, p["router"], *weights)
+        check_vma=False)(xf, p["router"], *weights)
     y = y.reshape(B, S, D)
 
     # shared experts: dense TP path outside the shard_map

@@ -28,16 +28,8 @@ __all__ = ["CostReport", "analyze_hlo", "xla_cost_analysis"]
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a flat dict of counters; newer releases return a
-    one-element list (one dict per program).  Returns a plain dict either
-    way, empty if XLA reports nothing.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a plain dict of XLA's counters."""
+    return dict(compiled.cost_analysis())
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")

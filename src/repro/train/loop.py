@@ -16,13 +16,15 @@ The loop wires every substrate together:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterator, Mapping
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.ckpt import CheckpointManager
 from repro.core.comm import Communicator
@@ -32,10 +34,14 @@ from repro.models.config import ModelConfig
 from repro.models.spec import param_specs_to_shapes
 from repro.runtime.compress import compress_with_feedback, init_error_feedback
 from repro.runtime.fault import HeartbeatMonitor, StragglerDetector
-from repro.train.offload_opt import OutOfCoreAdamW
+from repro.runtime.sharding import named_sharding, use_rules
+from repro.train.offload_opt import OutOfCoreAdamW, to_host
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
 
 __all__ = ["TrainConfig", "Trainer"]
+
+#: page cache of each offload-mode checkpoint window
+OFFLOAD_CKPT_CACHE_BYTES = 64 << 20
 
 
 @dataclasses.dataclass
@@ -56,6 +62,23 @@ class TrainConfig:
     probe_interval_s: float = 1.0
 
 
+class _HostTree(Mapping):
+    """Device arrays by name, each fetched to the host when it is read:
+    staging a checkpoint holds one tensor on the host at a time."""
+
+    def __init__(self, arrays: dict):
+        self._arrays = arrays
+
+    def __getitem__(self, k: str) -> np.ndarray:
+        return to_host(self._arrays[k])
+
+    def __iter__(self):
+        return iter(self._arrays)
+
+    def __len__(self) -> int:
+        return len(self._arrays)
+
+
 class Trainer:
     def __init__(self, model_cfg: ModelConfig, opt_cfg: AdamWConfig,
                  tcfg: TrainConfig, *, comm: Communicator | None = None,
@@ -68,6 +91,11 @@ class Trainer:
         self.rules = rules
         self.loss_fn = make_loss_fn(model_cfg)
         self.specs = param_specs(model_cfg)
+        # with a mesh, every param (and its Adam moments) lives on the
+        # sharding its spec's logical axes give under ``rules``
+        self.shardings = None if mesh is None else {
+            k: named_sharding(v.axes, v.shape, rules, mesh, context=k)
+            for k, v in self.specs.items()}
         self.metrics_log: list[dict[str, float]] = []
         self.hb = HeartbeatMonitor(self.comm.size)
         # probe-driven liveness: under the mp transport the other ranks are
@@ -121,8 +149,49 @@ class Trainer:
             loss, grads = accum(params, batch)
             return loss, {k: g.astype(jnp.bfloat16) for k, g in grads.items()}
 
-        self._fused_step = jax.jit(fused_step, donate_argnums=(0, 1, 2))
-        self._grads_step = jax.jit(grads_step)
+        if self.shardings is None:
+            self._fused_step = jax.jit(fused_step, donate_argnums=(0, 1, 2))
+            self._grads_step = jax.jit(grads_step)
+            return
+        # pin the outputs to the inputs' shardings: the next step then
+        # takes them as they are (no reshard, no second compile)
+        psh, rep = self.shardings, self._replicated()
+        ef_sh = psh if compression else rep
+        self._fused_step = jax.jit(
+            fused_step, donate_argnums=(0, 1, 2),
+            out_shardings=(psh, self._opt_shardings(), ef_sh, rep, rep))
+        self._grads_step = jax.jit(grads_step, out_shardings=(rep, psh))
+
+    # -- placement --------------------------------------------------------------
+    def _replicated(self) -> NamedSharding:
+        return NamedSharding(self.mesh, P())
+
+    def _opt_shardings(self) -> dict:
+        return {"m": self.shardings, "v": self.shardings,
+                "step": self._replicated()}
+
+    def _init_state(self, rng):
+        """Fresh params + (fused mode) Adam state, built where they live."""
+        fused = self.tcfg.mode == "fused"
+        if self.shardings is None:
+            params = init_params(self.specs, rng)
+            return params, init_opt_state(params) if fused else None
+        params = jax.jit(lambda r: init_params(self.specs, r),
+                         out_shardings=self.shardings)(rng)
+        opt_state = (jax.jit(init_opt_state,
+                             out_shardings=self._opt_shardings())(params)
+                     if fused else None)
+        return params, opt_state
+
+    def _place(self, items, dtype=None) -> dict:
+        """``(name, host array)`` pairs -> device arrays keyed by name, each
+        on its param's sharding when the trainer has a mesh.  A generator
+        of pairs is placed one array at a time: the host never holds them
+        all at once."""
+        if self.shardings is None:
+            return {k: jnp.asarray(v, dtype) for k, v in items}
+        return {k: jax.device_put(np.asarray(v, dtype), self.shardings[k])
+                for k, v in items}
 
     # -- checkpoint plumbing -----------------------------------------------------
     def _ckpt_specs(self, params) -> dict[str, tuple[tuple[int, ...], Any]]:
@@ -135,58 +204,78 @@ class Trainer:
             out["opt_step"] = ((), np.int32)
         return out
 
-    def _ckpt_tree(self, params, opt_state):
-        tree = {k: np.asarray(v) for k, v in params.items()}
+    def _ckpt_tree(self, params, opt_state) -> Mapping[str, np.ndarray]:
+        tree = dict(params)
         if self.tcfg.mode == "fused":
-            tree.update({f"opt_m/{k}": np.asarray(v)
-                         for k, v in opt_state["m"].items()})
-            tree.update({f"opt_v/{k}": np.asarray(v)
-                         for k, v in opt_state["v"].items()})
-            tree["opt_step"] = np.asarray(opt_state["step"])
-        return tree
+            tree.update({f"opt_m/{k}": v for k, v in opt_state["m"].items()})
+            tree.update({f"opt_v/{k}": v for k, v in opt_state["v"].items()})
+            tree["opt_step"] = opt_state["step"]
+        return _HostTree(tree)
 
     # -- main entry ---------------------------------------------------------------
     def run(self, data_iter: Iterator[dict[str, np.ndarray]],
             params: dict | None = None, *, restore: bool = True,
             stop_after: int | None = None,
             on_step: Callable[[int, dict], None] | None = None):
+        with (use_rules(self.rules, self.mesh) if self.mesh is not None
+              else contextlib.nullcontext()):
+            return self._run(data_iter, params, restore=restore,
+                             stop_after=stop_after, on_step=on_step)
+
+    def _run(self, data_iter, params, *, restore, stop_after, on_step):
         tcfg = self.tcfg
         rng = jax.random.PRNGKey(tcfg.seed)
         if params is None:
-            params = init_params(self.specs, rng)
-        if tcfg.mode == "fused":
+            params, opt_state = self._init_state(rng)
+        elif tcfg.mode == "fused":
             opt_state = init_opt_state(params)
-        else:
+        if tcfg.mode != "fused":
             shapes = {k: (tuple(v.shape), v.dtype) for k, v in params.items()}
             self._oo_opt = OutOfCoreAdamW(
                 self.comm, shapes, tcfg.ckpt_dir or "/tmp/repro_opt",
                 self.opt_cfg, memory_budget=tcfg.offload_memory_budget)
             self._oo_opt.initialize(params)
-            params = {k: jnp.asarray(v, jnp.bfloat16)
-                      for k, v in self._oo_opt.masters().items()}
+            params = self._place(((k, self._oo_opt.master(k))
+                                  for k in self._oo_opt.param_keys),
+                                 jnp.bfloat16)
             opt_state = None
         ef = init_error_feedback(params) if tcfg.compression else {
             k: jnp.zeros((1,), jnp.float32) for k in list(params)[:1]}
+        if self.shardings is not None:
+            # where the step puts it: the second step then compiles nothing
+            ef = jax.device_put(ef, self.shardings if tcfg.compression
+                                else self._replicated())
 
         start_step = 0
         if tcfg.ckpt_dir and tcfg.ckpt_every:
-            self._ckpt = CheckpointManager(tcfg.ckpt_dir, self.comm,
-                                           self._ckpt_specs(params))
+            # offload mode is for state the host barely holds: its
+            # checkpoints keep no snapshot (every param changes every
+            # step) and stream through a small page cache, so the host
+            # holds no copy of the params between saves
+            offload = tcfg.mode != "fused"
+            self._ckpt = CheckpointManager(
+                tcfg.ckpt_dir, self.comm, self._ckpt_specs(params),
+                snapshot_diff=not offload,
+                cache_bytes=OFFLOAD_CKPT_CACHE_BYTES if offload else None)
             if restore:
                 res = self._ckpt.restore()
                 if res is not None:
                     start_step = res.step
                     self.restored_step = res.step
-                    params = {k: jnp.asarray(res.tree[k])
-                              for k in self.specs}
+                    params = self._place((k, res.tree[k])
+                                         for k in self.specs)
                     if tcfg.mode == "fused":
                         opt_state = {
-                            "m": {k: jnp.asarray(res.tree[f"opt_m/{k}"])
-                                  for k in self.specs},
-                            "v": {k: jnp.asarray(res.tree[f"opt_v/{k}"])
-                                  for k in self.specs},
-                            "step": jnp.asarray(res.tree["opt_step"]),
+                            "m": self._place((k, res.tree[f"opt_m/{k}"])
+                                             for k in self.specs),
+                            "v": self._place((k, res.tree[f"opt_v/{k}"])
+                                             for k in self.specs),
+                            "step": (jnp.asarray(res.tree["opt_step"])
+                                     if self.shardings is None else
+                                     jax.device_put(res.tree["opt_step"],
+                                                    self._replicated())),
                         }
+                    del res  # the host copy of the state
 
         end = tcfg.steps if stop_after is None else min(tcfg.steps,
                                                         start_step + stop_after)
@@ -200,14 +289,16 @@ class Trainer:
                     params, opt_state, ef, batch)
             else:
                 loss, grads = self._grads_step(params, batch)
-                new_p = self._oo_opt.update(
-                    {k: np.asarray(v, np.float32) for k, v in grads.items()})
-                # update() returns only the keys present in grads (sparse/MoE
-                # updates skip the rest) -- merge, never replace wholesale
-                params = {**params,
-                          **{k: jnp.asarray(v, jnp.bfloat16)
-                             for k, v in new_p.items()}}
+                # the walk fetches one gradient and yields one new param at
+                # a time, placed as it comes: no host copy of them all.
+                # Only keys present in grads come back (sparse/MoE updates
+                # skip the rest) -- merge, never replace wholesale
+                params = {**params, **self._place(
+                    self._oo_opt.iter_update(grads), jnp.bfloat16)}
+                del grads  # off the device before the next step
                 stats = {"lr": 0.0, "gnorm": 0.0}
+            # the step ends when its new params are on the device
+            jax.block_until_ready(params)
             dt = time.monotonic() - t0
             self.hb.beat(self.comm.rank, step)
             # beat every *probed-live* rank through the communicator (and
@@ -224,11 +315,9 @@ class Trainer:
                 print(f"step {step:5d} loss {rec['loss']:.4f} "
                       f"({dt*1e3:.0f} ms)", flush=True)
             if self._ckpt and tcfg.ckpt_every and (step + 1) % tcfg.ckpt_every == 0:
-                tree = self._ckpt_tree(params, opt_state)
-                if tcfg.ckpt_async:
-                    self._ckpt.save_async(step + 1, tree)
-                else:
-                    self._ckpt.save(step + 1, tree)
+                save = (self._ckpt.save_async if tcfg.ckpt_async
+                        else self._ckpt.save)
+                save(step + 1, self._ckpt_tree(params, opt_state))
             if tcfg.mode == "offload" and self._oo_opt is not None \
                     and tcfg.ckpt_every and (step + 1) % tcfg.ckpt_every == 0:
                 self._oo_opt.sync()

@@ -40,6 +40,8 @@ not fitting: 12 bytes/param of optimizer state move off-HBM, leaving 2
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.comm import Communicator
@@ -48,10 +50,24 @@ from repro.core.storage import mark_span
 from repro.core.window import Request
 from repro.train.optimizer import AdamWConfig, cosine_schedule
 
-__all__ = ["OutOfCoreAdamW"]
+__all__ = ["OutOfCoreAdamW", "to_host"]
+
+
+#: walk blocks the page cache of a budgeted window holds: the walk keeps
+#: three arrays' blocks in flight (prefetch, current, write-behind)
+STREAM_CACHE_BLOCKS = 16
 
 
 class OutOfCoreAdamW:
+    """Blockwise AdamW over state kept in a storage window.
+
+    ``memory_budget`` bounds the host memory the state takes: that many
+    bytes stay pinned in memory (the combined window's ``factor='auto'``),
+    and the rest streams from storage through a page cache of
+    ``STREAM_CACHE_BLOCKS`` walk blocks.  Without it the whole state is
+    cached in memory and storage holds the flushed copy.
+    """
+
     def __init__(self, comm: Communicator, param_shapes: dict, directory: str,
                  cfg: AdamWConfig, *, memory_budget: int | None = None,
                  block_bytes: int = 1 << 22, writeback_interval: float | None = None):
@@ -68,12 +84,17 @@ class OutOfCoreAdamW:
         }
         if memory_budget is not None:
             info["storage_alloc_factor"] = "auto"
+            # split on a walk-block boundary: the spilled blocks then start
+            # on storage pages of their own and stream past the cache
+            memory_budget -= memory_budget % block_bytes
         # rank-local: each rank walks (and checkpoints) its own partition
         # of the optimizer window -- under SPMD every rank runs this same
         # code against its own segment, not rank 0's
         self.state = WindowedPyTree.allocate(
             comm, specs, info, rank=comm.rank, memory_budget=memory_budget,
-            block_bytes=block_bytes, writeback_interval=writeback_interval)
+            block_bytes=block_bytes, writeback_interval=writeback_interval,
+            cache_bytes=(None if memory_budget is None
+                         else STREAM_CACHE_BLOCKS * block_bytes))
         self.param_keys = sorted(param_shapes)
         self._initialized = False
         # window-block mask of pages some update wrote since the last sync
@@ -93,17 +114,29 @@ class OutOfCoreAdamW:
     def initialize(self, params: dict) -> None:
         """Seed master weights from the (bf16) device params; zero moments."""
         for k in self.param_keys:
-            p = np.asarray(params[k], np.float32)
+            p = to_host(params[k]).astype(np.float32)
             self.state.put(f"master/{k}", p)
-            self.state.put(f"m/{k}", np.zeros_like(p))
-            self.state.put(f"v/{k}", np.zeros_like(p))
+            zeros = np.zeros(p.shape, np.float32)  # untouched pages: no RSS
+            self.state.put(f"m/{k}", zeros)
+            self.state.put(f"v/{k}", zeros)
         self._initialized = True
 
     def update(self, grads: dict, *, grad_scale: float = 1.0,
                prefetch: bool = True, skip_clean: bool = True) -> dict:
-        """Streamed blockwise AdamW.  grads: host-fetchable arrays (bf16 ok).
-        Returns new bf16 params dict (numpy) to push to device -- only for
-        the keys present in ``grads`` (sparse/MoE updates skip the rest).
+        """Streamed blockwise AdamW; see :meth:`iter_update`.  Returns the
+        new f32 params (numpy) as one dict."""
+        return dict(self.iter_update(grads, grad_scale=grad_scale,
+                                     prefetch=prefetch,
+                                     skip_clean=skip_clean))
+
+    def iter_update(self, grads: dict, *, grad_scale: float = 1.0,
+                    prefetch: bool = True, skip_clean: bool = True):
+        """Streamed blockwise AdamW.  grads: host-fetchable arrays (bf16 ok,
+        device arrays are fetched one at a time).  Yields ``(name, new f32
+        param)`` (numpy) as each tensor is done, so a caller can push it
+        to the device before the next one exists -- only for the keys
+        present in ``grads`` (sparse/MoE updates skip the rest).  The step
+        counts once the first tensor is requested; consume every item.
 
         With ``prefetch`` (default), block ``i+1`` of all three state arrays
         is fetched with ``rget`` while block ``i``'s math runs, and block
@@ -121,11 +154,12 @@ class OutOfCoreAdamW:
         t = self.step
         b1c = 1 - cfg.b1 ** t
         b2c = 1 - cfg.b2 ** t
-        out = {}
         for k in self.param_keys:
             if k not in grads:  # sparse update: untouched expert/tensor
                 continue
-            g_full = np.asarray(grads[k], np.float32).ravel() * grad_scale
+            g_full = to_host(grads[k]).astype(np.float32).ravel()
+            if grad_scale != 1.0:
+                g_full *= grad_scale
             wa_m = self.state.array(f"m/{k}")
             wa_v = self.state.array(f"v/{k}")
             wa_p = self.state.array(f"master/{k}")
@@ -176,8 +210,7 @@ class OutOfCoreAdamW:
                 off += p.size
             Request.waitall(pending_writes)
             shape = self.state.slots[f"master/{k}"].shape
-            out[k] = new_p.reshape(shape)
-        return out
+            yield k, new_p.reshape(shape)
 
     def sync_masters_from_device(self, masters: dict, snapshot: dict, *,
                                  blocking: bool = True,
@@ -240,11 +273,24 @@ class OutOfCoreAdamW:
         self._touched = None  # only after a successful full flush
         return n
 
+    def master(self, name: str) -> np.ndarray:
+        return self.state.get(f"master/{name}")
+
     def masters(self) -> dict:
-        return {k: self.state.get(f"master/{k}") for k in self.param_keys}
+        return {k: self.master(k) for k in self.param_keys}
 
     def free(self) -> None:
         self.state.free()
+
+
+def to_host(x) -> np.ndarray:
+    """``x`` as a numpy array.  A ``jax.Array`` is copied on its device
+    first: ``np.asarray`` caches the host copy on the array it fetches,
+    which is then the short-lived copy and not ``x``, so no host copy
+    outlives the caller's use of it."""
+    if isinstance(x, jax.Array):
+        x = jnp.array(x, copy=True)
+    return np.asarray(x)
 
 
 def _decayable(name: str) -> bool:

@@ -108,7 +108,7 @@ def test_dirty_diff_matrix_matches_host_compare_on_write(
         idx = min(b * block_elems + (b % block_elems), n - 1)
         cur = cur.at[idx].add(jnp.asarray(1, dtype))
     flags = ops.dirty_blocks(cur, snap, block_elems=block_elems,
-                             tile_elems=64, impl="interpret")
+                             block_rows=8, impl="interpret")
     want = np.zeros(nblocks, dtype=bool)
     want[dirty] = True
     assert (np.asarray(flags, dtype=bool) == want).all()
@@ -140,7 +140,7 @@ def test_dirty_diff_tiled_bit_exact_nan(impl):
     cur = jnp.zeros((3, 500), jnp.float32).at[1, 499].set(jnp.nan)
     snap = cur.at[2, 0].add(1.0)
     flags = ops.dirty_blocks(cur.reshape(-1), snap.reshape(-1),
-                             block_elems=500, tile_elems=128, impl=impl)
+                             block_elems=500, block_rows=8, impl=impl)
     assert flags.tolist() == [0, 0, 1]
 
 
@@ -175,7 +175,7 @@ def test_dirty_pack_matrix_matches_host_compare_on_write(
         idx = min(b * block_elems + (b % block_elems), n - 1)
         cur = cur.at[idx].add(jnp.asarray(1, dtype))
     flags, packed, count = ops.dirty_pack(cur, snap, block_elems=block_elems,
-                                          tile_elems=64, impl="interpret")
+                                          block_rows=8, impl="interpret")
     want = np.zeros(nblocks, dtype=bool)
     want[dirty] = True
     assert (np.asarray(flags, dtype=bool) == want).all()
@@ -216,7 +216,7 @@ def test_dirty_pack_nan_and_layout(impl):
     cur = jnp.zeros((4, 500), jnp.float32).at[1, 499].set(jnp.nan)
     snap = cur.at[2, 0].add(1.0).at[3, 10].add(2.0)
     flags, packed, count = ops.dirty_pack(cur.reshape(-1), snap.reshape(-1),
-                                          block_elems=500, tile_elems=128,
+                                          block_elems=500, block_rows=8,
                                           impl=impl)
     assert flags.tolist() == [0, 0, 1, 1] and int(np.asarray(count)[0]) == 2
     runs = packed_run_layout(np.asarray(flags, bool), 500, 2000)
@@ -250,3 +250,46 @@ def test_flash_matches_model_attention():
                             q_block=32, kv_block=32, impl="interpret")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b.transpose(0, 2, 1, 3)),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_dirty_pack_above_8mib_matches_ref_bit_exact():
+    """A 9 MiB shard packs through the kernels (interpret mode) with the
+    same flags, count and packed rows as the jnp reference -- no size
+    routes the kernel path to the reference any more."""
+    pages, epp = 2304 + 3, 1024  # odd page count: a partial last grid step
+    rng = np.random.default_rng(7)
+    snap = rng.standard_normal(pages * epp).astype(np.float32)
+    snap[5 * epp:6 * epp] = np.nan  # unchanged NaN page stays clean
+    cur = snap.copy()
+    dirty = np.sort(rng.choice(np.delete(np.arange(pages), 5),
+                               size=pages // 12, replace=False))
+    cur[dirty * epp + 7] += 1.0
+    got = ops.dirty_pack(cur, snap, block_elems=epp, impl="interpret")
+    want = ops.dirty_pack(cur, snap, block_elems=epp, impl="ref")
+    flags = np.asarray(got[0])
+    assert flags.nonzero()[0].tolist() == dirty.tolist()
+    assert (flags == np.asarray(want[0])).all()
+    k = int(np.asarray(got[2])[0])
+    assert k == int(np.asarray(want[2])[0]) == len(dirty)
+    rows = np.asarray(got[1])[:k]
+    assert rows.dtype == np.uint32
+    assert (rows == np.asarray(want[1])[:k]).all()
+    assert (rows.view(np.uint8) ==
+            cur.reshape(pages, -1)[dirty].view(np.uint8)).all()
+
+
+def test_dirty_pack_fully_dirty_matches_ref_bit_exact():
+    """Every page dirty, over more pages than one grid step's flags and
+    far more than the copies the pack kernel keeps in flight: same flags,
+    count and packed rows as the jnp reference (interpret mode)."""
+    from repro.kernels.pack_diff import MAX_INFLIGHT, PACK_ROWS
+    pages, epp = PACK_ROWS + 3 * MAX_INFLIGHT + 5, 1024
+    snap = np.random.default_rng(3).standard_normal(pages * epp)
+    snap = snap.astype(np.float32)
+    cur = snap.copy()
+    cur[np.arange(pages) * epp + 11] += 1.0
+    got = ops.dirty_pack(cur, snap, block_elems=epp, impl="interpret")
+    want = ops.dirty_pack(cur, snap, block_elems=epp, impl="ref")
+    assert np.asarray(got[0]).all()
+    assert int(np.asarray(got[2])[0]) == int(np.asarray(want[2])[0]) == pages
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))

@@ -178,3 +178,47 @@ def test_unlink_and_discard(tmp_path):
     b.write(0, np.full(10, 1, np.uint8))
     b.close(unlink=True)
     assert not os.path.exists(p)
+
+
+def test_cached_streams_large_spans_past_a_small_cache(tmp_file):
+    """A span the cache could take only by evicting, none of it resident,
+    goes straight to the file (no per-page eviction) and back, and the
+    next sync reports and fsyncs it; a span with a resident page still
+    goes through the cache."""
+    from repro.core.storage import STREAM_MIN_BYTES
+    ps = 4096
+    n = 2 * STREAM_MIN_BYTES // ps           # pages per large span
+    b = CachedBacking(tmp_file, 4 * n * ps, cache_bytes=n // 2 * ps)
+    data = np.random.default_rng(0).integers(0, 256, 2 * n * ps, np.uint8)
+    b.write(0, data[:n * ps])                # fits in no cache: streams
+    assert b.evictions == 0 and b.tracker.dirty_count == 0
+    assert b.sync() == n * ps and b.sync() == 0
+    b.write(2 * n * ps, data[:ps])           # one page, cached and dirty
+    b.write(2 * n * ps, data)                # resident page: via the cache
+    assert b.tracker.dirty_count > 0
+    assert (b.read(0, n * ps) == data[:n * ps]).all()
+    assert (b.read(2 * n * ps, 2 * n * ps) == data).all()
+    b.close()
+    raw = np.fromfile(tmp_file, np.uint8)
+    assert (raw[:n * ps] == data[:n * ps]).all()
+    assert (raw[2 * n * ps:] == data).all()
+
+
+def test_cached_cold_read_loads_a_run_with_one_read(tmp_file):
+    """A cold multi-page read with room in the cache lands in the next
+    free slots in one read: same bytes, every page then resident."""
+    ps = 4096
+    data = np.random.default_rng(1).integers(0, 256, 64 * ps - 100, np.uint8)
+    b = CachedBacking(tmp_file, data.nbytes)
+    b.write(0, data)
+    b.close()
+    b = CachedBacking(tmp_file, data.nbytes)
+    page_reads = []
+    pread = b.file.pread
+    b.file.pread = lambda *a: page_reads.append(a) or pread(*a)
+    assert (b.read(3 * ps + 17, 10 * ps) == data[3 * ps + 17:13 * ps + 17]).all()
+    assert not page_reads
+    assert b.faults == 11 and (b._slot_of[3:14] >= 0).all()
+    assert (b.read(60 * ps, data.nbytes - 60 * ps) == data[60 * ps:]).all()
+    assert (b.read(0, data.nbytes) == data).all()
+    b.close()

@@ -96,6 +96,34 @@ def test_trainer_offload_mode(tmp_path):
     tr.close()
 
 
+def test_trainer_offload_checkpoint_keeps_no_host_copy(tmp_path):
+    """Offload-mode checkpoints keep no host snapshot and stream through
+    a bounded page cache, and a fresh trainer restores the saved params
+    bit for bit."""
+    from repro.train.loop import OFFLOAD_CKPT_CACHE_BYTES
+    cfg = get_config("internlm2-1.8b", smoke=True)
+    opt = AdamWConfig(lr=2e-3, warmup_steps=0, total_steps=100)
+    tc = TrainConfig(steps=4, mode="offload", log_every=0,
+                     ckpt_dir=str(tmp_path / "oo"), ckpt_every=2)
+    tr = Trainer(cfg, opt, tc)
+    params, _ = tr.run(FixedBatch(_fixed_batch(cfg)))
+    ck = tr._ckpt
+    assert ck.saves == 2 and not ck._snapshots
+    for wt in ck.windows.values():
+        backing = wt.win.segments[0].backing
+        assert not backing.compare_on_write
+        assert backing.capacity * backing.page_size <= OFFLOAD_CKPT_CACHE_BYTES
+    saved = {k: np.asarray(v) for k, v in params.items()}
+    tr.close()
+    tr = Trainer(cfg, opt, tc)
+    restored, _ = tr.run(iter(()), stop_after=0)
+    assert tr.restored_step == 4
+    for k, v in saved.items():
+        got = np.asarray(restored[k])
+        assert got.dtype == v.dtype and got.tobytes() == v.tobytes(), k
+    tr.close()
+
+
 def test_trainer_compression_still_learns():
     cfg = get_config("internlm2-1.8b", smoke=True)
     opt = AdamWConfig(lr=2e-3, warmup_steps=0, total_steps=100,
