@@ -48,6 +48,7 @@ from repro.core.comm import Communicator
 from repro.core.offload import WindowedPyTree
 from repro.core.storage import dirty_runs, mark_span
 from repro.core.window import Request
+from repro.perf.trace import span
 
 __all__ = ["CheckpointManager", "RestoreResult"]
 
@@ -105,27 +106,30 @@ class CheckpointManager:
         # the same bytes a second time).
         self.snapshot_diff = snapshot_diff
         self._snapshots: dict[str, dict[str, np.ndarray]] = {}
-        for name in self.names:
-            info = {
-                "alloc_type": "storage",
-                "storage_alloc_filename": os.path.join(directory, f"ckpt_{name}.bin"),
-                "striping_factor": str(striping_factor),
-                "striping_unit": str(striping_unit),
-            }
-            if replication > 1:
-                info["storage_alloc_replication"] = str(replication)
-            self.windows[name] = WindowedPyTree.allocate(
-                comm, self.specs, info, rank=self.rank, mechanism=mechanism,
-                writeback_interval=writeback_interval, cache_bytes=cache_bytes)
-            if not snapshot_diff and cache_bytes is None:
-                # selective sync even under whole-tree puts:
-                for seg in self._segments(self.windows[name]):
-                    if hasattr(seg, "backing") and hasattr(seg.backing,
-                                                           "compare_on_write"):
-                        seg.backing.compare_on_write = True
+        with span("ckpt.open"):
+            for name in self.names:
+                info = {
+                    "alloc_type": "storage",
+                    "storage_alloc_filename": os.path.join(
+                        directory, f"ckpt_{name}.bin"),
+                    "striping_factor": str(striping_factor),
+                    "striping_unit": str(striping_unit),
+                }
+                if replication > 1:
+                    info["storage_alloc_replication"] = str(replication)
+                self.windows[name] = WindowedPyTree.allocate(
+                    comm, self.specs, info, rank=self.rank,
+                    mechanism=mechanism,
+                    writeback_interval=writeback_interval,
+                    cache_bytes=cache_bytes)
+                if not snapshot_diff and cache_bytes is None:
+                    # selective sync even under whole-tree puts:
+                    for seg in self._segments(self.windows[name]):
+                        if hasattr(seg, "backing") and hasattr(
+                                seg.backing, "compare_on_write"):
+                            seg.backing.compare_on_write = True
         self._turn = 0
         self.saves = 0
-        self.bytes_flushed_total = 0
         self._pending: Request | None = None
         self._pending_target: str | None = None
 
@@ -215,23 +219,33 @@ class CheckpointManager:
         crcs: dict[str, int] = {}
         new_snap: dict[str, np.ndarray] = {}
         for k in sorted(self.specs):
-            arr = np.ascontiguousarray(tree[k], dtype=self.specs[k][1])
-            crcs[k] = _crc(arr)
+            nbytes = wt.slots[k].nbytes
+            with span("ckpt.fetch", nbytes=nbytes):
+                # a tree of device arrays fetches each one as it is read
+                arr = np.ascontiguousarray(tree[k], dtype=self.specs[k][1])
+            with span("ckpt.crc", nbytes=nbytes):
+                crcs[k] = _crc(arr)
             raw = arr.view(np.uint8).ravel()
             if self.snapshot_diff:
-                new_snap[k] = raw.copy()
+                with span("ckpt.snapshot", nbytes=nbytes):
+                    new_snap[k] = raw.copy()
             if snap is not None:
                 slot = wt.slots[k]
                 # span payloads slice the manager-owned snapshot copy, so
                 # a caller mutating its tree before the flush runs cannot
                 # corrupt the staged bytes
                 staged = new_snap[k]
-                for b0, b1 in dirty_runs(self._page_diff(raw, snap[k], ps)):
-                    lo, hi = b0 * ps, min(b1 * ps, raw.nbytes)
-                    spans.append((slot.offset + lo, staged[lo:hi]))
-                    mark_span(mask, slot.offset + lo, slot.offset + hi, ps)
+                with span("ckpt.diff") as sp:
+                    changed = self._page_diff(raw, snap[k], ps)
+                    for b0, b1 in dirty_runs(changed):
+                        lo, hi = b0 * ps, min(b1 * ps, raw.nbytes)
+                        spans.append((slot.offset + lo, staged[lo:hi]))
+                        mark_span(mask, slot.offset + lo, slot.offset + hi,
+                                  ps)
+                    sp.set(pages=int(changed.sum()))
             else:
-                wt.put(k, arr)
+                with span("ckpt.put", nbytes=nbytes):
+                    wt.put(k, arr)
         if self.snapshot_diff:
             self._snapshots[target] = new_snap
         return crcs, mask, spans
@@ -245,7 +259,8 @@ class CheckpointManager:
         snapshot that no longer describes the cache.  (Span-apply failures
         at flush time are handled the same way by save()/wait().)"""
         try:
-            return self._stage(target, wt, tree)
+            with span("ckpt.stage"):
+                return self._stage(target, wt, tree)
         except BaseException:
             self._snapshots.pop(target, None)
             raise
@@ -268,9 +283,9 @@ class CheckpointManager:
             raise
         finally:
             wt.win.unlock(self.rank)
-        self._write_manifest(step, target, crcs)
+        with span("ckpt.commit"):
+            self._write_manifest(step, target, crcs)
         self.saves += 1
-        self.bytes_flushed_total += flushed
         return flushed
 
     def save_async(self, step: int, tree: Mapping[str, Any]) -> Request:
@@ -295,9 +310,9 @@ class CheckpointManager:
         def _commit(flushed: int) -> None:
             # Runs on the write-back thread after a successful flush; the
             # manifest only ever names fully-persisted data.
-            self._write_manifest(step, target, crcs)
+            with span("ckpt.commit"):
+                self._write_manifest(step, target, crcs)
             self.saves += 1
-            self.bytes_flushed_total += flushed
 
         self._pending = wt.sync_async(exclusive=True, on_complete=_commit,
                                       mask=mask, spans=spans)
@@ -309,7 +324,8 @@ class CheckpointManager:
             req, self._pending = self._pending, None
             target, self._pending_target = self._pending_target, None
             try:
-                req.wait()
+                with span("ckpt.wait"):
+                    req.wait()
             except BaseException:
                 # Failed flush: the window's snapshot no longer reflects
                 # disk; invalidate so the next save to it replays in full.
@@ -331,14 +347,22 @@ class CheckpointManager:
         wt = self.windows[target]
         tree: dict[str, np.ndarray] = {}
         for k in sorted(self.specs):
-            arr = wt.get(k)
-            if _crc(arr) != m["crc"].get(k):
+            nbytes = wt.slots[k].nbytes
+            with span("ckpt.read", nbytes=nbytes):
+                arr = wt.get(k)
+            with span("ckpt.crc", nbytes=nbytes):
+                ok = _crc(arr) == m["crc"].get(k)
+            if not ok:
                 return None  # torn/corrupt slot
             tree[k] = arr
         return RestoreResult(step=int(m["step"]), tree=tree, manifest=m)
 
     def restore(self) -> RestoreResult | None:
         """Latest valid checkpoint, falling back A->B via the prev manifest."""
+        with span("ckpt.restore"):
+            return self._restore()
+
+    def _restore(self) -> RestoreResult | None:
         res = self._try_restore(self._manifest_path())
         if res is not None:
             return res

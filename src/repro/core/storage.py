@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from repro.perf.trace import span
+
 __all__ = [
     "DEFAULT_PAGE_SIZE",
     "DirtyTracker",
@@ -284,7 +286,6 @@ class _BackingBase:
         self.page_size = page_size
         self.tracker = DirtyTracker(size, page_size)
         self.closed = False
-        self.sync_count = 0
         self.bytes_flushed = 0
 
     def _check(self, offset: int, nbytes: int) -> None:
@@ -360,7 +361,6 @@ class MmapBacking(_BackingBase):
         """
         if self.closed:
             raise RuntimeError("backing is closed")
-        self.sync_count += 1
         if full:
             self._mm.flush()
             self.tracker.snapshot_and_clear()
@@ -534,8 +534,10 @@ class CachedBacking(_BackingBase):
         lo = b0 * self.page_size
         hi = min(b1 * self.page_size, self.size)
         flat = self._slots[s0:s0 + n].reshape(-1)
-        for c in range(0, hi - lo, FLUSH_CHUNK):
-            self.file.preadinto(lo + c, flat[c:min(c + FLUSH_CHUNK, hi - lo)])
+        with span("storage.read", nbytes=hi - lo):
+            for c in range(0, hi - lo, FLUSH_CHUNK):
+                self.file.preadinto(lo + c,
+                                    flat[c:min(c + FLUSH_CHUNK, hi - lo)])
         flat[hi - lo:] = 0
         self._slot_of[b0:b1] = np.arange(s0, s0 + n)
         self._block_of[s0:s0 + n] = np.arange(b0, b1)
@@ -562,19 +564,24 @@ class CachedBacking(_BackingBase):
         with self._io_lock:
             b0, b1 = self.tracker.block_range(offset, nbytes)
             if self._streams(b0, b1):
-                for c in range(0, nbytes, FLUSH_CHUNK):
-                    self.file.preadinto(offset + c, out[c:c + FLUSH_CHUNK])
+                with span("storage.read", nbytes=nbytes):
+                    for c in range(0, nbytes, FLUSH_CHUNK):
+                        self.file.preadinto(offset + c,
+                                            out[c:c + FLUSH_CHUNK])
                 return out
             if self._load_run(b0, b1):
                 lo = offset - b0 * self.page_size
                 s0 = int(self._slot_of[b0])
-                out[:] = self._slots[s0:s0 + b1 - b0].reshape(-1)[lo:lo + nbytes]
+                with span("storage.copy", nbytes=nbytes):
+                    out[:] = self._slots[s0:s0 + b1 - b0].reshape(-1)[
+                        lo:lo + nbytes]
                 return out
             # fast path: aligned read, everything resident -> one gather
             if (offset % self.page_size == 0 and nbytes % self.page_size == 0
                     and nbytes and (self._slot_of[b0:b1] >= 0).all()):
                 slots = self._slot_of[b0:b1]
-                out[:] = self._slots[slots].reshape(-1)
+                with span("storage.copy", nbytes=nbytes):
+                    out[:] = self._slots[slots].reshape(-1)
                 self._refbit[slots] = True
                 return out
             pos = offset
@@ -674,12 +681,13 @@ class CachedBacking(_BackingBase):
         """
         if self.closed:
             raise RuntimeError("backing is closed")
-        with self._io_lock:
-            self.sync_count += 1
-            n = self._flush_locked(full=full, mask=mask) + self._unsynced
+        with self._io_lock, span("storage.flush") as sp:
+            with span("storage.write"):
+                n = self._flush_locked(full=full, mask=mask) + self._unsynced
             if n:
                 try:
-                    self.file.fsync()
+                    with span("storage.fsync"):
+                        self.file.fsync()
                 except BaseException:
                     # fsync failure: durability of the just-written blocks is
                     # unknown -- conservatively re-dirty the whole window so a
@@ -687,6 +695,7 @@ class CachedBacking(_BackingBase):
                     self.tracker.mark(0, self.size)
                     raise
                 self._unsynced = 0
+            sp.set(nbytes=n)
             return n
 
     def _flush_locked(self, full: bool = False,
@@ -779,9 +788,10 @@ class _Ticket:
     """
 
     __slots__ = ("_event", "_fn", "key", "nbytes", "sample", "result",
-                 "exception", "_next")
+                 "exception", "_next", "submitted_at")
 
     def __init__(self, fn, key, nbytes: int = 0, sample: bool = False):
+        self.submitted_at = time.monotonic()
         self._event = threading.Event()
         self._fn = fn
         self.key = key
@@ -998,7 +1008,8 @@ class WritebackPool:
                 concurrency = self._running_samples
             t0 = time.monotonic()
             try:
-                t.result = t._fn()
+                with span("storage.task", queued_s=t0 - t.submitted_at):
+                    t.result = t._fn()
             except BaseException as e:  # surfaced at Request.wait()
                 t.exception = e
             dt = time.monotonic() - t0

@@ -70,6 +70,8 @@ import os
 
 import numpy as np
 
+from repro.perf.trace import span
+
 __all__ = ["Transport", "TransportError", "ACC_OPS", "BATCH_OPS",
            "DEFERRABLE_OPS", "ENV_TIMEOUTS", "apply_accumulate",
            "apply_get_accumulate", "apply_compare_and_swap",
@@ -203,11 +205,16 @@ def apply_masked_spans(seg, spans, mask) -> int:
     segments, inside the owner's progress thread for remote ones.  Returns
     bytes flushed.
     """
-    for offset, data in spans:
-        seg.write(offset, np.asarray(data, dtype=np.uint8).ravel())
-    mark = getattr(seg, "mark_blocks", None)
-    if mask is not None and mark is not None:
-        mark(mask)
+    with span("storage.apply") as sp:
+        nbytes = 0
+        for offset, data in spans:
+            data = np.asarray(data, dtype=np.uint8).ravel()
+            seg.write(offset, data)
+            nbytes += data.nbytes
+        mark = getattr(seg, "mark_blocks", None)
+        if mask is not None and mark is not None:
+            mark(mask)
+        sp.set(nbytes=nbytes)
     return seg.sync(mask=mask)
 
 

@@ -224,6 +224,8 @@ from typing import Any
 
 import numpy as np
 
+from repro.perf.trace import span
+
 from .hints import Info, WindowHints
 from .resilience.placement import ReplicaPlacement
 from .storage import (DEFAULT_PAGE_SIZE, DirtyTracker, WritebackPool,
@@ -721,7 +723,8 @@ class Window:
             target_rank,
             lambda seg: self.comm.transport.get(seg, off, count * dt.itemsize),
             handle=handle)
-        return raw.view(dt)[:count].copy()
+        with span("window.copy", nbytes=count * dt.itemsize):
+            return raw.view(dt)[:count].copy()
 
     # kept as an alias: the op table now lives with the transport layer so
     # the multiprocess worker applies the same reductions target-side
@@ -1781,12 +1784,18 @@ class Window:
         Returns the flush's :class:`Request` (``wait()`` -> bytes flushed),
         or the bytes directly with ``blocking=True``.
         """
-        from repro.kernels.dirty_diff import changed_elem_spans
-        from repro.kernels.ops import resolve_impl
         shards = list(shards)
         if not shards:
             raise WindowError(
                 "sync_shards_from_device requires at least one shard")
+        with span("window.device_sync", shards=len(shards)):
+            return self._sync_shards_from_device(rank, shards, blocking,
+                                                 impl)
+
+    def _sync_shards_from_device(self, rank: int, shards: list,
+                                 blocking: bool, impl: str | None):
+        from repro.kernels.dirty_diff import changed_elem_spans
+        from repro.kernels.ops import resolve_impl
         self._check_shard_overlap(shards)
         resolved = resolve_impl(impl)
         stats = self.device_sync_stats()
@@ -1799,26 +1808,33 @@ class Window:
             spans = []
             mask = None
             for cur, snap, target_disp in shards:
-                flags = self._device_flags(rank, cur, snap, impl=resolved)
+                with span("window.fetch_bitmap"):
+                    flags = self._device_flags(rank, cur, snap,
+                                               impl=resolved)
                 _, block_elems, _ = self._device_page_geometry(rank,
                                                                cur.dtype)
                 itemsize = np.dtype(cur.dtype).itemsize
                 byte_off = target_disp * self.disp_unit
                 nelems = int(np.prod(np.shape(cur), dtype=np.int64))
-                m = self._flags_to_window_mask(rank, flags, cur.dtype,
-                                               nelems, target_disp)
-                mask = m if mask is None else mask | m
+                with span("window.spans"):
+                    m = self._flags_to_window_mask(rank, flags, cur.dtype,
+                                                   nelems, target_disp)
+                    mask = m if mask is None else mask | m
                 # reference path: one device->host slice per changed span
                 # (same changed_elem_spans geometry as the packed path)
                 cur_flat = cur.reshape(-1)
-                for lo_e, hi_e in changed_elem_spans(flags, block_elems,
-                                                     nelems):
-                    chunk = np.ascontiguousarray(
-                        np.asarray(cur_flat[lo_e:hi_e]))
-                    spans.append((byte_off + lo_e * itemsize,
-                                  chunk.view(np.uint8).ravel()))
-                    stats["span_transfers"] += 1
-                    stats["logical_bytes"] += (hi_e - lo_e) * itemsize
+                with span("window.fetch_payload") as sp:
+                    fetched = 0
+                    for lo_e, hi_e in changed_elem_spans(flags, block_elems,
+                                                         nelems):
+                        chunk = np.ascontiguousarray(
+                            np.asarray(cur_flat[lo_e:hi_e]))
+                        spans.append((byte_off + lo_e * itemsize,
+                                      chunk.view(np.uint8).ravel()))
+                        stats["span_transfers"] += 1
+                        stats["logical_bytes"] += (hi_e - lo_e) * itemsize
+                        fetched += chunk.nbytes
+                    sp.set(nbytes=fetched)
         # normalize here with the tolerant device-diff rule (a device bitmap
         # may pad past the last page); sync/flush_async then see an
         # exact-length mask and keep their strict validation for everyone
@@ -1860,15 +1876,20 @@ class Window:
         from repro.kernels.ops import dirty_pack
         from repro.kernels.pack_diff import packed_run_layout
         per = []
-        for cur, snap, target_disp in shards:
-            self._check_shard_pair(cur, snap)
-            _, block_elems, _ = self._device_page_geometry(rank, cur.dtype)
-            flags_d, packed_d, _count_d = dirty_pack(
-                cur, snap, block_elems=block_elems, impl=impl)
-            per.append((flags_d, packed_d, cur, target_disp, block_elems))
-        # one bitmap fetch covers every shard (int32 flags, concatenated)
-        flags_host = np.asarray(jnp.concatenate([p[0] for p in per])
-                                if len(per) > 1 else per[0][0])
+        with span("window.launch"):
+            for cur, snap, target_disp in shards:
+                self._check_shard_pair(cur, snap)
+                _, block_elems, _ = self._device_page_geometry(rank,
+                                                               cur.dtype)
+                flags_d, packed_d, _count_d = dirty_pack(
+                    cur, snap, block_elems=block_elems, impl=impl)
+                per.append((flags_d, packed_d, cur, target_disp,
+                            block_elems))
+        # one bitmap fetch covers every shard (int32 flags, concatenated);
+        # it waits for the kernels
+        with span("window.fetch_bitmap"):
+            flags_host = np.asarray(jnp.concatenate([p[0] for p in per])
+                                    if len(per) > 1 else per[0][0])
         stats["bitmap_transfers"] += 1
         parts = []
         split = 0
@@ -1883,29 +1904,32 @@ class Window:
         spans: list[tuple[int, np.ndarray]] = []
         mask: np.ndarray | None = None
         if parts:
-            payload = np.asarray(parts[0] if len(parts) == 1
-                                 else jnp.concatenate(parts))
-            payload = payload.reshape(-1).view(np.uint8)
+            with span("window.fetch_payload") as sp:
+                payload = np.asarray(parts[0] if len(parts) == 1
+                                     else jnp.concatenate(parts))
+                payload = payload.reshape(-1).view(np.uint8)
+                sp.set(nbytes=payload.nbytes)
             stats["payload_transfers"] += 1
             stats["payload_bytes"] += payload.nbytes
         else:
             payload = np.zeros(0, np.uint8)
-        base = 0
-        for f, (flags_d, packed_d, cur, target_disp, block_elems) in zip(
-                shard_flags, per):
-            itemsize = np.dtype(cur.dtype).itemsize
-            byte_off = target_disp * self.disp_unit
-            nelems = int(np.prod(np.shape(cur), dtype=np.int64))
-            m = self._flags_to_window_mask(rank, f.astype(bool), cur.dtype,
-                                           nelems, target_disp)
-            mask = m if mask is None else mask | m
-            for lo_e, hi_e, poff in packed_run_layout(f, block_elems,
-                                                      nelems):
-                b0 = base + poff * itemsize
-                spans.append((byte_off + lo_e * itemsize,
-                              payload[b0:b0 + (hi_e - lo_e) * itemsize]))
-                stats["logical_bytes"] += (hi_e - lo_e) * itemsize
-            base += int(f.sum()) * block_elems * itemsize
+        with span("window.spans"):
+            base = 0
+            for f, (flags_d, packed_d, cur, target_disp, block_elems) in zip(
+                    shard_flags, per):
+                itemsize = np.dtype(cur.dtype).itemsize
+                byte_off = target_disp * self.disp_unit
+                nelems = int(np.prod(np.shape(cur), dtype=np.int64))
+                m = self._flags_to_window_mask(rank, f.astype(bool),
+                                               cur.dtype, nelems, target_disp)
+                mask = m if mask is None else mask | m
+                for lo_e, hi_e, poff in packed_run_layout(f, block_elems,
+                                                          nelems):
+                    b0 = base + poff * itemsize
+                    spans.append((byte_off + lo_e * itemsize,
+                                  payload[b0:b0 + (hi_e - lo_e) * itemsize]))
+                    stats["logical_bytes"] += (hi_e - lo_e) * itemsize
+                base += int(f.sum()) * block_elems * itemsize
         return spans, mask
 
     # -- resilience: live rebuild -------------------------------------------

@@ -32,6 +32,7 @@ from repro.core.resilience import FailureDetector
 from repro.models import init_params, make_loss_fn, param_specs
 from repro.models.config import ModelConfig
 from repro.models.spec import param_specs_to_shapes
+from repro.perf.trace import span
 from repro.runtime.compress import compress_with_feedback, init_error_feedback
 from repro.runtime.fault import HeartbeatMonitor, StragglerDetector
 from repro.runtime.sharding import named_sharding, use_rules
@@ -83,6 +84,10 @@ class Trainer:
     def __init__(self, model_cfg: ModelConfig, opt_cfg: AdamWConfig,
                  tcfg: TrainConfig, *, comm: Communicator | None = None,
                  mesh=None, rules=None):
+        with span("train.build"):
+            self._build(model_cfg, opt_cfg, tcfg, comm, mesh, rules)
+
+    def _build(self, model_cfg, opt_cfg, tcfg, comm, mesh, rules) -> None:
         self.model_cfg = model_cfg
         self.opt_cfg = opt_cfg
         self.tcfg = tcfg
@@ -193,6 +198,21 @@ class Trainer:
         return {k: jax.device_put(np.asarray(v, dtype), self.shardings[k])
                 for k, v in items}
 
+    def _place_restored(self, tree: Mapping[str, np.ndarray], opt_state):
+        """A restored checkpoint's params (and fused Adam state) on the
+        device; ``opt_state`` passes through in offload mode."""
+        params = self._place((k, tree[k]) for k in self.specs)
+        if self.tcfg.mode == "fused":
+            opt_state = {
+                "m": self._place((k, tree[f"opt_m/{k}"]) for k in self.specs),
+                "v": self._place((k, tree[f"opt_v/{k}"]) for k in self.specs),
+                "step": (jnp.asarray(tree["opt_step"])
+                         if self.shardings is None else
+                         jax.device_put(tree["opt_step"],
+                                        self._replicated())),
+            }
+        return params, opt_state
+
     # -- checkpoint plumbing -----------------------------------------------------
     def _ckpt_specs(self, params) -> dict[str, tuple[tuple[int, ...], Any]]:
         out = {k: (tuple(v.shape), np.dtype(jnp.dtype(v.dtype).name))
@@ -226,7 +246,8 @@ class Trainer:
         tcfg = self.tcfg
         rng = jax.random.PRNGKey(tcfg.seed)
         if params is None:
-            params, opt_state = self._init_state(rng)
+            with span("train.init"):
+                params, opt_state = self._init_state(rng)
         elif tcfg.mode == "fused":
             opt_state = init_opt_state(params)
         if tcfg.mode != "fused":
@@ -262,19 +283,10 @@ class Trainer:
                 if res is not None:
                     start_step = res.step
                     self.restored_step = res.step
-                    params = self._place((k, res.tree[k])
-                                         for k in self.specs)
-                    if tcfg.mode == "fused":
-                        opt_state = {
-                            "m": self._place((k, res.tree[f"opt_m/{k}"])
-                                             for k in self.specs),
-                            "v": self._place((k, res.tree[f"opt_v/{k}"])
-                                             for k in self.specs),
-                            "step": (jnp.asarray(res.tree["opt_step"])
-                                     if self.shardings is None else
-                                     jax.device_put(res.tree["opt_step"],
-                                                    self._replicated())),
-                        }
+                    with span("train.place", nbytes=sum(
+                            v.nbytes for v in res.tree.values())):
+                        params, opt_state = self._place_restored(
+                            res.tree, opt_state)
                     del res  # the host copy of the state
 
         end = tcfg.steps if stop_after is None else min(tcfg.steps,
@@ -284,21 +296,23 @@ class Trainer:
             batch = next(data_iter)
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             t0 = time.monotonic()
-            if tcfg.mode == "fused":
-                params, opt_state, ef, loss, stats = self._fused_step(
-                    params, opt_state, ef, batch)
-            else:
-                loss, grads = self._grads_step(params, batch)
-                # the walk fetches one gradient and yields one new param at
-                # a time, placed as it comes: no host copy of them all.
-                # Only keys present in grads come back (sparse/MoE updates
-                # skip the rest) -- merge, never replace wholesale
-                params = {**params, **self._place(
-                    self._oo_opt.iter_update(grads), jnp.bfloat16)}
-                del grads  # off the device before the next step
-                stats = {"lr": 0.0, "gnorm": 0.0}
-            # the step ends when its new params are on the device
-            jax.block_until_ready(params)
+            with span("train.step"):
+                if tcfg.mode == "fused":
+                    params, opt_state, ef, loss, stats = self._fused_step(
+                        params, opt_state, ef, batch)
+                else:
+                    loss, grads = self._grads_step(params, batch)
+                    # the walk fetches one gradient and yields one new
+                    # param at a time, placed as it comes: no host copy of
+                    # them all.  Only keys present in grads come back
+                    # (sparse/MoE updates skip the rest) -- merge, never
+                    # replace wholesale
+                    params = {**params, **self._place(
+                        self._oo_opt.iter_update(grads), jnp.bfloat16)}
+                    del grads  # off the device before the next step
+                    stats = {"lr": 0.0, "gnorm": 0.0}
+                # the step ends when its new params are on the device
+                jax.block_until_ready(params)
             dt = time.monotonic() - t0
             self.hb.beat(self.comm.rank, step)
             # beat every *probed-live* rank through the communicator (and
@@ -317,7 +331,8 @@ class Trainer:
             if self._ckpt and tcfg.ckpt_every and (step + 1) % tcfg.ckpt_every == 0:
                 save = (self._ckpt.save_async if tcfg.ckpt_async
                         else self._ckpt.save)
-                save(step + 1, self._ckpt_tree(params, opt_state))
+                with span("train.save"):
+                    save(step + 1, self._ckpt_tree(params, opt_state))
             if tcfg.mode == "offload" and self._oo_opt is not None \
                     and tcfg.ckpt_every and (step + 1) % tcfg.ckpt_every == 0:
                 self._oo_opt.sync()
