@@ -285,7 +285,10 @@ def window_sync_phase(run_dir: str, *, nbytes: int = GiB,
                                     impl=impl, blocking=True)
         shards_s = time.perf_counter() - t1
         st = win.device_sync_stats()
-        _check_stats(st, want_impl, 1, 1, nshard_dirty * PAGE)
+        # packed rows come in each shard's width: one payload per width
+        _check_stats(st, want_impl, 1, len(dtypes), nshard_dirty * PAGE)
+        if st["narrow_tile_shards"] != 2:
+            raise AssertionError(f"bf16 and int8 not in native tiles: {st}")
     finally:
         win.free()
     _check_file(path, b"".join(b[1] for b in blobs), "sync_shards")

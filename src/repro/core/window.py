@@ -215,6 +215,7 @@ statically by ``python -m repro.analysis.rmalint`` (rules catalogue:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import itertools
@@ -850,7 +851,9 @@ class Window:
         ``bitmap_transfers`` the tiny per-set bitmap fetches;
         ``span_transfers`` per-span slice fetches on the reference path;
         ``payload_bytes``/``logical_bytes`` the packed bytes fetched vs the
-        changed bytes shipped.
+        changed bytes shipped; ``narrow_tile_shards`` the shards the kernels
+        diffed and packed in their own sub-32-bit width (native tiles, see
+        ``repro.kernels.ops.narrow_tiles``).
         """
         st = getattr(self, "_dev_sync_stats", None)
         if st is None:
@@ -858,7 +861,8 @@ class Window:
                 "syncs": 0, "pallas_syncs": 0, "interpret_syncs": 0,
                 "ref_syncs": 0, "payload_transfers": 0,
                 "bitmap_transfers": 0, "span_transfers": 0,
-                "payload_bytes": 0, "logical_bytes": 0}
+                "payload_bytes": 0, "logical_bytes": 0,
+                "narrow_tile_shards": 0}
         return st
 
     #: pending-list length that triggers a prune pass in _register --
@@ -1766,8 +1770,9 @@ class Window:
         On the kernel path (``pallas`` or ``interpret``) each shard's
         changed blocks are compacted *on device* (prefix-sum placement)
         and every shard's compacted buffer crosses PCIe in ONE contiguous
-        transfer per shard set -- plus one tiny bitmap fetch -- regardless
-        of how fragmented the dirty set is.  The reference path
+        transfer per shard set (one per width when dirty shards of several
+        widths meet) -- plus one tiny bitmap fetch -- regardless of how
+        fragmented the dirty set is.  The reference path
         (``impl='ref'``) fetches one slice per changed span.  Both paths derive
         their spans from the same ``changed_elem_spans`` geometry, so the
         bytes shipped are identical; see :meth:`device_sync_stats` for the
@@ -1863,22 +1868,25 @@ class Window:
 
     def _packed_device_spans(self, rank: int, shards, impl: str,
                              stats: dict):
-        """Fused-kernel span gathering: ONE payload transfer per shard set.
+        """Fused-kernel span gathering: ONE payload transfer per width.
 
         Runs ``dirty_pack`` per shard (bitmap + on-device compacted dirty
         blocks), fetches all shards' bitmaps in one transfer and all
-        shards' compacted blocks (byte views, concatenated on device) in
-        one more, then rebuilds the span list host-side from the shared
-        ``changed_elem_spans`` geometry (``packed_run_layout``).
+        shards' compacted blocks (concatenated on device) in one more --
+        one per width when dirty shards of several widths (32-, 16- and
+        8-bit) meet in one set -- then rebuilds the span list host-side
+        from the shared ``changed_elem_spans`` geometry
+        (``packed_run_layout``).
         """
         import jax.numpy as jnp
 
-        from repro.kernels.ops import dirty_pack
+        from repro.kernels.ops import dirty_pack, narrow_tiles
         from repro.kernels.pack_diff import packed_run_layout
         per = []
         with span("window.launch"):
             for cur, snap, target_disp in shards:
                 self._check_shard_pair(cur, snap)
+                stats["narrow_tile_shards"] += narrow_tiles(cur.dtype)
                 _, block_elems, _ = self._device_page_geometry(rank,
                                                                cur.dtype)
                 flags_d, packed_d, _count_d = dirty_pack(
@@ -1891,7 +1899,10 @@ class Window:
             flags_host = np.asarray(jnp.concatenate([p[0] for p in per])
                                     if len(per) > 1 else per[0][0])
         stats["bitmap_transfers"] += 1
-        parts = []
+        # packed rows come in the shard's width (uint32 words, or uint16 /
+        # uint8 tiles): the dirty rows of each width are concatenated on
+        # device and fetched as one payload
+        groups: dict = {}
         split = 0
         shard_flags = []
         for flags_d, packed_d, cur, _disp, _be in per:
@@ -1899,22 +1910,21 @@ class Window:
             split += flags_d.shape[0]
             shard_flags.append(f)
             k = int(f.sum())
-            if k:  # uint32 rows of one page each, whatever the dtype
-                parts.append(packed_d[:k])
-        spans: list[tuple[int, np.ndarray]] = []
-        mask: np.ndarray | None = None
-        if parts:
+            if k:
+                groups.setdefault(packed_d.dtype, []).append(packed_d[:k])
+        payloads = {}
+        for dt, parts in groups.items():
             with span("window.fetch_payload") as sp:
                 payload = np.asarray(parts[0] if len(parts) == 1
                                      else jnp.concatenate(parts))
-                payload = payload.reshape(-1).view(np.uint8)
+                payloads[dt] = payload.reshape(-1).view(np.uint8)
                 sp.set(nbytes=payload.nbytes)
             stats["payload_transfers"] += 1
             stats["payload_bytes"] += payload.nbytes
-        else:
-            payload = np.zeros(0, np.uint8)
+        spans: list[tuple[int, np.ndarray]] = []
+        mask: np.ndarray | None = None
         with span("window.spans"):
-            base = 0
+            base = collections.Counter()  # bytes of each payload used
             for f, (flags_d, packed_d, cur, target_disp, block_elems) in zip(
                     shard_flags, per):
                 itemsize = np.dtype(cur.dtype).itemsize
@@ -1923,13 +1933,15 @@ class Window:
                 m = self._flags_to_window_mask(rank, f.astype(bool),
                                                cur.dtype, nelems, target_disp)
                 mask = m if mask is None else mask | m
+                dt = packed_d.dtype
                 for lo_e, hi_e, poff in packed_run_layout(f, block_elems,
                                                           nelems):
-                    b0 = base + poff * itemsize
+                    b0 = base[dt] + poff * itemsize
                     spans.append((byte_off + lo_e * itemsize,
-                                  payload[b0:b0 + (hi_e - lo_e) * itemsize]))
+                                  payloads[dt][b0:b0 + (hi_e - lo_e)
+                                               * itemsize]))
                     stats["logical_bytes"] += (hi_e - lo_e) * itemsize
-                base += int(f.sum()) * block_elems * itemsize
+                base[dt] += int(f.sum()) * block_elems * itemsize
         return spans, mask
 
     # -- resilience: live rebuild -------------------------------------------

@@ -9,18 +9,21 @@ tiny bitmap plus the dirty blocks then cross PCIe, feeding the same
 ``DirtyTracker`` bitmap as the host-side compare-on-write path
 (``Window.sync_from_device`` / ``flush_async(mask=...)``).
 
-Layout: every block is a whole number of (8, 128) uint32 tiles -- the
-inputs are ``(nblocks, S, 128)`` words with ``S % 8 == 0`` (a 4 KiB page
-is exactly one tile; ``repro.kernels.ops`` builds this view from any
-dtype and zero-pads smaller blocks).  Each grid step compares ``block_rows`` blocks
-at once, folds each block's tiles to one 128-lane row, and transposes so
+Layout: every block is a whole number of 4 KiB tiles in the width the
+TPU stores it in -- the inputs are ``(nblocks, S, 128)`` uint32 words
+with ``S % 8 == 0``, uint16 with ``S % 16 == 0`` or uint8 with
+``S % 32 == 0`` (a 4 KiB page is exactly one tile; ``repro.kernels.ops``
+builds this view from any dtype and zero-pads smaller blocks).  Each grid
+step compares ``block_rows`` blocks at once: a packed 16- or 8-bit block
+is read as the uint32 words its tiles already are (``pltpu.bitcast``, no
+work), each block's tiles fold to one 128-lane row, and a transpose lets
 the step's flags leave as one lane-dense ``(1, block_rows)`` int32 row.
 The last step may be partial: the rows it reads past ``nblocks`` are
 garbage and their flags are cropped.
 
-The compare is on uint32 *bit patterns*, so an unchanged block full of
-NaNs stays clean (IEEE ``NaN != NaN`` would dirty it), matching the host
-page cache's byte-level compare exactly.
+The compare is on *bit patterns*, so an unchanged block full of NaNs
+stays clean (IEEE ``NaN != NaN`` would dirty it), matching the host page
+cache's byte-level compare exactly.
 """
 
 from __future__ import annotations
@@ -30,10 +33,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dirty_diff_tpu", "changed_elem_spans"]
 
 LANES = 128
+#: the widths the kernel takes, each in whole 4 KiB tiles
+TILE_DTYPES = (jnp.dtype(jnp.uint32), jnp.dtype(jnp.uint16),
+               jnp.dtype(jnp.uint8))
 #: words each input block of one grid step aims at (1 MiB of uint32):
 #: two inputs, double-buffered, stay well inside the default scoped VMEM
 STEP_WORDS = 1 << 18
@@ -65,25 +72,34 @@ def changed_elem_spans(flags, block_elems: int,
     return out
 
 
-def _kernel(sublanes, cur_ref, snap_ref, flag_ref):
-    ne = (cur_ref[...] != snap_ref[...]).astype(jnp.int32)
-    rows = ne.shape[0] // sublanes
-    per_block = jnp.max(ne.reshape(rows, sublanes, LANES), axis=1)
+def _kernel(words, cur_ref, snap_ref, flag_ref):
+    """``words``: rows of 128 uint32 words in one block."""
+    cur, snap = cur_ref[...], snap_ref[...]
+    if cur.dtype != jnp.uint32:  # packed narrow tiles: the same words
+        cur = pltpu.bitcast(cur, jnp.uint32)
+        snap = pltpu.bitcast(snap, jnp.uint32)
+    ne = (cur != snap).astype(jnp.int32)
+    rows = ne.shape[0] // words
+    per_block = jnp.max(ne.reshape(rows, words, LANES), axis=1)
     flag_ref[0] = jnp.max(per_block.T, axis=0, keepdims=True)
 
 
 def dirty_diff_tpu(cur: jax.Array, snap: jax.Array, *,
                    block_rows: int | None = None,
                    interpret: bool = False) -> jax.Array:
-    """cur, snap: (nblocks, S, 128) uint32, ``S % 8 == 0`` -> (nblocks,)
-    int32 (1 = changed)."""
+    """cur, snap: (nblocks, S, 128) uint32, uint16 or uint8 whose blocks
+    are whole tiles (``S`` a multiple of 8, 16 or 32) -> (nblocks,) int32
+    (1 = changed)."""
     if cur.shape != snap.shape or cur.dtype != snap.dtype:
         raise ValueError("cur/snap shape or dtype mismatch")
     nb, sub, lanes = cur.shape
-    if lanes != LANES or sub % 8 or cur.dtype != jnp.uint32:
-        raise ValueError(f"need (nblocks, 8k, 128) uint32 words, got "
-                         f"{cur.shape} {cur.dtype}")
-    rows = block_rows or _default_block_rows(sub)
+    size = cur.dtype.itemsize
+    if (lanes != LANES or cur.dtype not in TILE_DTYPES
+            or sub % (32 // size)):
+        raise ValueError(f"need (nblocks, S, 128) uint32, uint16 or uint8 "
+                         f"in whole tiles, got {cur.shape} {cur.dtype}")
+    words = sub * size // 4  # word rows of one block
+    rows = block_rows or _default_block_rows(words)
     if rows % 8:
         raise ValueError(f"block_rows must be a multiple of 8, got {rows}")
     if nb < rows:  # a single step: pad it whole (small by construction)
@@ -93,7 +109,7 @@ def dirty_diff_tpu(cur: jax.Array, snap: jax.Array, *,
     steps = pl.cdiv(cur.shape[0], rows)
     flat = (cur.shape[0] * sub, LANES)  # same tiled layout: a bitcast
     out = pl.pallas_call(
-        functools.partial(_kernel, sub),
+        functools.partial(_kernel, words),
         grid=(steps,),
         in_specs=[pl.BlockSpec((rows * sub, LANES), lambda i: (i, 0))] * 2,
         out_specs=pl.BlockSpec((1, 1, rows), lambda i: (i, 0, 0)),

@@ -21,10 +21,11 @@ from repro.kernels.rg_lru import rg_lru_tpu
 from repro.kernels.ssd_scan import ssd_scan_tpu
 
 __all__ = ["flash_attention", "ssd_scan", "rg_lru_scan", "dirty_blocks",
-           "dirty_pack", "use_pallas", "resolve_impl"]
+           "dirty_pack", "narrow_tiles", "use_pallas", "resolve_impl"]
 
-#: uint32 words in one (8, 128) TPU tile: the device-sync kernels' unit
-TILE_WORDS = 8 * 128
+#: bytes of one TPU tile of 32-bit words, (8, 128): the device-sync
+#: kernels' unit (narrower dtypes pack 16 or 32 rows into it)
+TILE_BYTES = 8 * 128 * 4
 
 
 def use_pallas() -> bool:
@@ -86,37 +87,35 @@ def rg_lru_scan(a, gx, *, block=256, impl: str | None = None):
     return y[:, :S]
 
 
-def _block_words(x, block_elems: int):
-    """Flatten ``x``, zero-pad to a block multiple, and view it as
-    ``(nblocks, words)`` uint32 -- the bit patterns, whatever the dtype."""
+def narrow_tiles(dtype) -> bool:
+    """Whether a shard of ``dtype`` is diffed and packed in its own width
+    (native tiles) rather than as uint32 words."""
+    return jnp.dtype(dtype).itemsize < 4
+
+
+def _blocks(x, block_elems: int):
+    """Flatten ``x``, zero-pad to a block multiple, and view its bit
+    patterns as ``(nblocks, n)``: uint32 words for 32-bit and wider dtypes,
+    the same-width unsigned integer below that (a free bitcast -- no
+    strided slice or shift, which a TPU runs as a lane shuffle)."""
     x = jnp.asarray(x).reshape(-1)
-    nbytes = block_elems * x.dtype.itemsize
-    if nbytes % 4:
-        raise ValueError(f"a block of {block_elems} x {x.dtype} is "
-                         f"{nbytes} bytes, not a whole number of words")
     pad = (-x.shape[0]) % block_elems
     if pad:
         x = jnp.pad(x, (0, pad))
     size = x.dtype.itemsize
-    if size >= 4:
-        x = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    else:
-        # little-endian: element j of a word fills bits [8*size*j, ...).
-        # Strided slices and shifts, not a (.., k)-shaped bitcast, whose
-        # minor dim of k would be padded to 128 lanes on a TPU.
-        u = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * size}"))
-        k = 4 // size
-        x = u[0::k].astype(jnp.uint32)
-        for j in range(1, k):
-            x = x | (u[j::k].astype(jnp.uint32) << (8 * size * j))
-    return x.reshape(-1, nbytes // 4)
+    if narrow_tiles(x.dtype):
+        x = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * size}"))
+        return x.reshape(-1, block_elems)
+    x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return x.reshape(-1, block_elems * size // 4)
 
 
 def _tiles(x):
-    """(nblocks, words) -> (nblocks, S, 128): each block zero-padded to
-    whole (8, 128) tiles (a 4 KiB page is exactly one)."""
-    nb, words = x.shape
-    pad = (-words) % TILE_WORDS
+    """(nblocks, n) -> (nblocks, S, 128): each block zero-padded to whole
+    4 KiB tiles of its dtype -- (8, 128) uint32, (16, 128) uint16, (32, 128)
+    uint8 -- the tiles the TPU stores it in (a 4 KiB page is exactly one)."""
+    nb, n = x.shape
+    pad = (-n) % (TILE_BYTES // x.dtype.itemsize)
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
     return x.reshape(nb, -1, 128)
@@ -146,8 +145,8 @@ def dirty_blocks(cur, snap, *, block_elems=1024, block_rows=None,
     one kernel grid step compares (a multiple of 8).
     """
     impl = resolve_impl(impl)
-    c = _block_words(cur, block_elems)
-    s = _block_words(snap, block_elems)
+    c = _blocks(cur, block_elems)
+    s = _blocks(snap, block_elems)
     if impl == "ref":
         return ref.dirty_diff_ref(c, s)
     return dirty_diff_tpu(_tiles(c), _tiles(s), block_rows=block_rows,
@@ -157,21 +156,24 @@ def dirty_blocks(cur, snap, *, block_elems=1024, block_rows=None,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def dirty_pack(cur, snap, *, block_elems=1024, block_rows=None,
                impl: str | None = None):
-    """Fused diff+pack: ``(flags (nb,) int32, packed (nb, words) uint32,
-    count (1,) int32)``.
+    """Fused diff+pack: ``(flags (nb,) int32, packed (nb, n), count (1,)
+    int32)``.
 
     ``packed[:count]`` holds the changed blocks' bytes in block order, one
-    row of ``block_elems * itemsize / 4`` words per block, so one
-    device->host fetch of those rows moves every changed byte;
-    ``repro.kernels.pack_diff.packed_run_layout`` maps the bitmap to span
-    geometry shared with the non-fused path.  Layout normalization
-    (flatten, zero-pad to a block multiple, word view) matches
-    :func:`dirty_blocks` exactly, so the two bitmaps always agree.  Every
-    impl packs at every size; on a TPU the default is the compiled kernel.
+    row per block, so one device->host fetch of those rows moves every
+    changed byte; ``repro.kernels.pack_diff.packed_run_layout`` maps the
+    bitmap to span geometry shared with the non-fused path.  A row is
+    ``block_elems * itemsize / 4`` uint32 words for 32-bit and wider
+    dtypes, and ``block_elems`` elements of the same-width unsigned integer
+    (:func:`narrow_tiles`) below that; either way its row-major bytes are
+    the block's bytes.  Layout normalization (flatten, zero-pad to a block
+    multiple, bit-pattern view) matches :func:`dirty_blocks` exactly, so
+    the two bitmaps always agree.  Every impl packs at every size; on a TPU
+    the default is the compiled kernel.
     """
     impl = resolve_impl(impl)
-    c = _block_words(cur, block_elems)
-    s = _block_words(snap, block_elems)
+    c = _blocks(cur, block_elems)
+    s = _blocks(snap, block_elems)
     if impl == "ref":
         return ref.diff_pack_ref(c, s)
     flags, packed, count = diff_pack_tpu(

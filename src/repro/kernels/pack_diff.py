@@ -11,15 +11,17 @@ of how fragmented the dirty set is.
 
 The pack kernel never stages the packed buffer in VMEM, so any size packs:
 the flags arrive in SMEM ``PACK_ROWS`` at a time, and each flagged block is
-one HBM->HBM DMA (a 4 KiB page is one contiguous (8, 128) uint32 tile)
-into packed row ``count``; the TPU grid is sequential, so ``count`` is a
-running prefix sum kept in SMEM.  At most ``MAX_INFLIGHT`` copies are
+one HBM->HBM DMA (a 4 KiB page is one contiguous tile, whatever its
+width) into packed row ``count``; the TPU grid is sequential, so ``count``
+is a running prefix sum kept in SMEM.  At most ``MAX_INFLIGHT`` copies are
 in flight at once, and each grid step waits for the DMAs it started.  Rows at index >= final count are uninitialized and must not be
 read.
 
-Bit-pattern semantics match ``dirty_diff``: the inputs are uint32 word
-views (built by ``repro.kernels.ops``), so unchanged NaN blocks stay
-clean and the packed rows hold the exact bytes of the current tensor.
+Bit-pattern semantics match ``dirty_diff``: the inputs are unsigned
+integer views in the width the TPU stores them in (uint32 words, or
+uint16/uint8 tiles; built by ``repro.kernels.ops``), so unchanged NaN
+blocks stay clean and the packed rows hold the exact bytes of the current
+tensor.
 """
 
 from __future__ import annotations
@@ -128,11 +130,12 @@ def pack_rows_tpu(flags: jax.Array, cur: jax.Array, *,
 
 def diff_pack_tpu(cur: jax.Array, snap: jax.Array, *,
                   block_rows: int | None = None, interpret: bool = False):
-    """cur, snap: (nblocks, S, 128) uint32 words.
+    """cur, snap: (nblocks, S, 128) uint32, uint16 or uint8 tiles
+    (:func:`~repro.kernels.dirty_diff.dirty_diff_tpu`).
 
-    Returns ``(flags (nb,) int32, packed (nb, S, 128) uint32, count (1,)
-    int32)``.  ``packed[:count]`` are the dirty blocks in block order;
-    rows past ``count`` are uninitialized.
+    Returns ``(flags (nb,) int32, packed like cur, count (1,) int32)``.
+    ``packed[:count]`` are the dirty blocks in block order; rows past
+    ``count`` are uninitialized.
     """
     flags = dirty_diff_tpu(cur, snap, block_rows=block_rows,
                            interpret=interpret)

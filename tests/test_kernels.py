@@ -78,7 +78,8 @@ def test_dirty_diff_sweep(dtype):
     assert flags[2] == 1 and flags[5] == 1 and int(flags.sum()) == 2
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8,
+                                   jnp.uint16])
 @pytest.mark.parametrize("block_elems,n", [
     (128, 1024),    # aligned
     (96, 960),      # odd block size
@@ -92,7 +93,7 @@ def test_dirty_diff_matrix_matches_host_compare_on_write(
     from repro.core.storage import CachedBacking
 
     key = jax.random.PRNGKey(n + block_elems)
-    if dtype == jnp.int8:
+    if jnp.issubdtype(dtype, jnp.integer):
         snap = jax.random.randint(key, (n,), -100, 100, jnp.int32).astype(dtype)
     else:
         snap = (jax.random.normal(key, (n,), jnp.float32) * 4).astype(dtype)
@@ -144,7 +145,8 @@ def test_dirty_diff_tiled_bit_exact_nan(impl):
     assert flags.tolist() == [0, 0, 1]
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8,
+                                   jnp.uint16])
 @pytest.mark.parametrize("block_elems,n", [
     (128, 1024),    # aligned
     (96, 960),      # odd block size
@@ -159,7 +161,7 @@ def test_dirty_pack_matrix_matches_host_compare_on_write(
     from repro.core.storage import CachedBacking
 
     key = jax.random.PRNGKey(n * 3 + block_elems)
-    if dtype == jnp.int8:
+    if jnp.issubdtype(dtype, jnp.integer):
         snap = jax.random.randint(key, (n,), -100, 100, jnp.int32).astype(dtype)
     else:
         snap = (jax.random.normal(key, (n,), jnp.float32) * 4).astype(dtype)
@@ -224,6 +226,59 @@ def test_dirty_pack_nan_and_layout(impl):
     rows = np.asarray(packed)[:2].view(np.uint8).reshape(2, -1)[:, :2000]
     want = np.asarray(cur, np.float32)[2:4].reshape(2, -1).view(np.uint8)
     assert (rows == want).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint16, jnp.bfloat16, jnp.int8])
+def test_dirty_pack_page_is_one_native_tile(tmp_path, dtype):
+    """A 4 KiB page of a sub-32-bit dtype is one (16, 128) or (32, 128)
+    tile of its own width: the kernels (interpret mode) agree bit for bit
+    with the jnp reference and the host compare-on-write bitmap, pack the
+    pages in the shard's width, and keep an unchanged NaN page clean."""
+    from repro.core.storage import CachedBacking
+
+    itemsize = jnp.dtype(dtype).itemsize
+    be = 4096 // itemsize
+    pages = 21
+    n = pages * be - 3  # ragged last page
+    rng = np.random.default_rng(itemsize)
+    snap = jnp.asarray(rng.integers(-100, 100, n), jnp.int32).astype(dtype)
+    if dtype == jnp.bfloat16:
+        snap = snap.at[4 * be:5 * be].set(jnp.nan)  # page 4: NaN, unchanged
+    dirty = [0, 5, 6, 13, pages - 1]
+    cur = snap
+    for b in dirty:
+        cur = cur.at[min(b * be + 7 * b, n - 1)].add(jnp.asarray(1, dtype))
+    assert ops._tiles(ops._blocks(cur, be)).shape == (pages, 32 // itemsize,
+                                                      128)
+    got = ops.dirty_pack(cur, snap, block_elems=be, block_rows=8,
+                         impl="interpret")
+    want = ops.dirty_pack(cur, snap, block_elems=be, impl="ref")
+    flags = np.asarray(got[0])
+    assert flags.nonzero()[0].tolist() == dirty
+    assert (flags == np.asarray(want[0])).all()
+    assert (np.asarray(ops.dirty_blocks(cur, snap, block_elems=be,
+                                        block_rows=8, impl="interpret"))
+            == flags).all()
+    k = int(np.asarray(got[2])[0])
+    assert k == int(np.asarray(want[2])[0]) == len(dirty)
+    rows = np.asarray(got[1])[:k]
+    assert rows.dtype == np.dtype(f"uint{8 * itemsize}")
+    assert rows.shape == (k, be)
+    assert np.array_equal(rows, np.asarray(want[1])[:k])
+    cur_bytes = np.asarray(cur).tobytes()
+    cur_rows = np.zeros((pages, 4096), np.uint8)
+    cur_rows.reshape(-1)[:len(cur_bytes)] = np.frombuffer(cur_bytes, np.uint8)
+    assert (rows.view(np.uint8) == cur_rows[dirty]).all()
+
+    backing = CachedBacking(str(tmp_path / "t.bin"), n * itemsize,
+                            page_size=4096, cache_bytes=pages * 4096,
+                            compare_on_write=True)
+    backing.write(0, np.frombuffer(np.asarray(snap).tobytes(), np.uint8))
+    backing.sync()
+    backing.write(0, np.frombuffer(cur_bytes, np.uint8))
+    host_bits = backing.tracker._bits.copy()
+    backing.close(unlink=True)
+    assert (host_bits == flags.astype(bool)).all()
 
 
 def test_dirty_diff_feeds_tracker():

@@ -306,6 +306,42 @@ def test_sync_shards_packed_single_transfer(tmp_path):
     win.free()
 
 
+@pytest.mark.parametrize("narrow_dirty", [True, False])
+def test_sync_shards_mixed_widths(tmp_path, narrow_dirty):
+    """An f32 shard and a uint16 shard at page-aligned displacements: the
+    uint16 one is diffed and packed in its own tiles (counted in
+    ``narrow_tile_shards``), the file ends up equal to both shards' bytes,
+    and the payload takes one transfer per width that has dirty pages."""
+    jnp = pytest.importorskip("jax.numpy")
+    comm = Communicator(1)
+    win = Window.allocate(comm, PAGES * PAGE, info=storage_info(tmp_path))
+    f_snap = np.arange(4 * PAGE // 4, dtype=np.float32)     # pages 0-3
+    h_snap = np.arange(6 * PAGE // 2, dtype=np.uint16)      # pages 6-11
+    win.put(f_snap, 0, 0)
+    win.put(h_snap, 0, 6 * PAGE)
+    win.sync(0)
+    f_cur = f_snap.copy()
+    f_cur[PAGE // 4 + 3] = -1.0                             # page 1
+    h_cur = h_snap.copy()
+    if narrow_dirty:
+        h_cur[5] ^= 0x8000                                  # page 6
+        h_cur[4 * PAGE // 2 + 9] += 1                       # page 10
+    win.sync_shards_from_device(
+        0, [(jnp.asarray(f_cur), jnp.asarray(f_snap), 0),
+            (jnp.asarray(h_cur), jnp.asarray(h_snap), 6 * PAGE)],
+        impl="interpret", blocking=True)
+    st = win.device_sync_stats()
+    assert st["narrow_tile_shards"] == 1
+    assert st["bitmap_transfers"] == 1
+    assert st["payload_transfers"] == (2 if narrow_dirty else 1), st
+    assert st["payload_bytes"] == (3 if narrow_dirty else 1) * PAGE
+    raw = np.fromfile(tmp_path / "w.bin", np.uint8)
+    assert (raw[:f_cur.nbytes] == f_cur.view(np.uint8)).all()
+    assert (raw[6 * PAGE:6 * PAGE + h_cur.nbytes]
+            == h_cur.view(np.uint8)).all()
+    win.free()
+
+
 def test_offload_opt_sync_masters_from_device(tmp_path):
     """Device-resident master weights persist through the merged shard
     mask: only the changed pages of the changed tensors flush."""

@@ -9,6 +9,7 @@ these tests loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,4 +84,38 @@ def test_diff_pack_compiles_for_v5e(one_chip, nbytes, dtype):
     text = c.as_text()
     # both kernels of the pair: the diff and the DMA pack
     assert text.count("tpu_custom_call") >= 2
+    _fits(c)
+
+
+#: 1-D shards of sub-32-bit dtypes: HACC's uint16 ``mask`` field at its
+#: particle count, and an int8 field of 64 MiB and three pages
+NARROW = [(100_001_792, jnp.uint16), ((64 << 20) + 3 * PAGE, jnp.int8)]
+NARROW_IDS = [f"{n}-{jnp.dtype(d).name}" for n, d in NARROW]
+
+
+def _strided_or_gathered(hlo: str) -> list[str]:
+    """Optimized-HLO lines that slice with a stride above 1 or gather: what
+    a lane-strided word view of a 1-D array compiles to."""
+    strided = re.compile(r"slice=\{\[\d+:\d+:(\d+)\]")
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if any(int(s) > 1 for s in strided.findall(line))
+            or re.search(r"\bgather\(", line)]
+
+
+@pytest.mark.parametrize("kernel", ["dirty_blocks", "dirty_pack"])
+@pytest.mark.parametrize("n,dtype", NARROW, ids=NARROW_IDS)
+def test_narrow_1d_shard_compiles_in_native_tiles(one_chip, kernel, n,
+                                                  dtype):
+    """A 1-D sub-32-bit shard reaches the kernels as a view in its own
+    width: it compiles for the v5e, fits, and has no strided slice or
+    gather (which a TPU runs as a lane shuffle at a fraction of HBM
+    speed)."""
+    be = PAGE // jnp.dtype(dtype).itemsize
+    fn = getattr(ops, kernel)
+    x = jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+    c = jax.jit(lambda a, b: fn(a, b, block_elems=be, impl="pallas")).lower(
+        x, x).compile()
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert _strided_or_gathered(text) == []
     _fits(c)
